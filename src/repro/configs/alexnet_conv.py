@@ -64,7 +64,8 @@ class CNNConfig:
     classes: int
     bins: int = 16  # PASM dictionary size, one dictionary per conv layer
     groups: int = 1  # reduction-axis codebook groups per layer (1 = paper rule)
-    impl: str = "kernel"  # auto | einsum | kernel | kernel_implicit | pas_kernel
+    # auto | einsum | kernel | kernel_implicit | pas_kernel | pas_kernel_implicit
+    impl: str = "kernel"
     padding: str = "valid_centred"  # stack-wide: valid_centred | valid | same
     layout: str = "NCHW"  # stack-wide: NCHW | NHWC
     packed: bool = False  # int4-pack the conv dictionaries at quantize time

@@ -256,12 +256,11 @@ def _implicit_fits(
     bins = params.bins if params is not None else 16
     has_bias = params is None or params.bias is not None
     K = conv.K + pad_k
-    bm, bn, bk, _ = _kops._pick_blocks(
+    _, bn, bk, _ = _kops._pick_blocks(
         geom.P_rows, K, conv.c_out, K // groups, packed
     )
-    bm = _kops._pool_bm(bm, pool)
     return _kops.conv_whole_image_fits(
-        geom, hp, wp, bm=bm, bn=bn, bk=bk, bins=bins, packed=packed,
+        geom, hp, wp, bn=bn, bk=bk, bins=bins, packed=packed,
         pas=False, has_bias=has_bias, vmem_budget=budget,
     )
 
@@ -702,7 +701,7 @@ def _pool_order_patches(patches: jax.Array, batch: int, oh: int, ow: int,
     The explicit fused-pool GEMM's row contract: each consecutive ``pool²``
     rows form one pool window (so the kernel's epilogue max is a pure
     reshape), floor-remainder pixels are dropped before the GEMM ever runs —
-    the same rows the implicit kernel's window-major ``patch_tile`` walks.
+    the same windows the implicit kernel pools in its epilogue.
     """
     K = patches.shape[1]
     ohp, owp = oh // pool, ow // pool
@@ -719,22 +718,21 @@ def _einsum_sharded(patches, w, bias, relu: bool, mesh):
     :func:`repro.launch.mesh.n_shard_axis` rule) as the Pallas engines, so
     dense params shard like dictionary params do.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.kernels.ref import apply_epilogue  # pallas-free
     from repro.launch.mesh import n_shard_axis
 
     ns = n_shard_axis(mesh, w.shape[1])
     if bias is None:
-        return shard_map(
+        return jax.shard_map(
             lambda pt, wl: apply_epilogue(pt @ wl, None, relu),
             mesh=mesh, in_specs=(P("data", None), P(None, ns)),
-            out_specs=P("data", ns), check_rep=False,
+            out_specs=P("data", ns), check_vma=False,
         )(patches, w)
-    return shard_map(
+    return jax.shard_map(
         lambda pt, wl, bl: apply_epilogue(pt @ wl, bl, relu),
         mesh=mesh, in_specs=(P("data", None), P(None, ns), P(ns)),
-        out_specs=P("data", ns), check_rep=False,
+        out_specs=P("data", ns), check_vma=False,
     )(patches, w, bias)
 
 
@@ -916,9 +914,11 @@ def quantize_conv_weights(
 
     Returns ``(codebook (B,), bin_idx (M, C, KY, KX) uint8)`` — the raw
     pieces for callers that build their own :meth:`ConvParams.shared`.  The
-    clustering itself is :meth:`PasmParams.quantize` over the kernel
-    flattened to a single column, so conv and dense layers share one
-    quantizer.
+    clustering is the 1-D k-means every PASM quantizer shares, over the
+    kernel flattened to one axis: the TPU compiler takes about a minute per
+    conv layer to relayout the kernel's ``(…, ky, kx)`` tiles into a 2-D
+    ``(1, n)`` or ``(n, 1)`` matrix, and seconds into a vector.
     """
-    p = PasmParams.quantize(kernel.reshape(-1, 1), bins, iters=iters)
-    return p.codebook[0], p.idx.reshape(kernel.shape).astype(jnp.uint8)
+    cb, idx = _pasm._kmeans_1d(kernel.reshape(-1).astype(jnp.float32), bins,
+                               iters)
+    return cb, idx.reshape(kernel.shape).astype(jnp.uint8)

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 __all__ = ["flash_attention_kernel_call"]
 
@@ -104,7 +103,7 @@ def flash_attention_kernel_call(
         ],
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, g, i: (b, g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BKV, G, Sq, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")
         ),
         interpret=interpret,

@@ -1,7 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handles: shape padding to tile multiples, block-size selection, packed-int4
-plumbing, interpret-mode fallback on CPU, and a custom VJP so PASM layers are
+plumbing, interpret mode off-TPU (the CPU test path — any TPU backend compiles
+the kernels with Mosaic, and chip runs pass ``interpret=False`` explicitly),
+and a custom VJP so PASM layers are
 differentiable (gradient w.r.t. activations flows through the dequantized
 weight; quantized weights are leaves without gradients — QAT uses
 ``repro.core.qat`` on the dense master copy instead).
@@ -31,10 +33,12 @@ from repro.core import pasm as _pasm
 from repro.kernels import ref as _ref
 from repro.kernels.pas_histogram import pas_conv_kernel_call, pas_matmul_kernel_call
 from repro.kernels.pasm_matmul import (
+    LANE,
     ConvGeom,
     SlabPlan,
     pasm_conv_kernel_call,
     pasm_matmul_kernel_call,
+    phase_slabs,
 )
 
 __all__ = [
@@ -91,13 +95,11 @@ def _n_spec(mesh, n: int):
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    from jax.experimental.shard_map import shard_map
-
-    # check_rep=False: the N-replicated fallback computes identical outputs
-    # on every model-axis device, which the rep checker cannot prove through
-    # a pallas_call.
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    # check_vma=False: the N-replicated fallback computes identical outputs
+    # on every model-axis device, which the varying-axes checker cannot
+    # prove through a pallas_call.
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def _shard_gemm(mesh, n_cols, local_fn, operands, *, x_rank, out_rank,
@@ -229,127 +231,112 @@ def _check_pool_operand(x, pool: int, mesh=None, n_data: int = 1) -> None:
         )
 
 
-def _conv_block_vmem_bytes(*, bm: int, bn: int, bk: int, bins: int,
-                           packed: bool = False, pas: bool = False,
-                           has_bias: bool = True, pool: int = 1) -> int:
+def _conv_block_vmem_bytes(*, bmp: int, pool: int, bn: int, bk: int,
+                           bins: int, packed: bool = False, pas: bool = False,
+                           has_bias: bool = True, itemsize: int = 4) -> int:
     """Non-image VMEM bytes of one implicit-conv grid step.
 
-    Counts what actually sits in VMEM next to the image block: the idx tile
-    (uint8, halved when packed), the codebook row (+1 reserved pad bin, the
-    worst case), the bias row, the output block — each ×2 because Pallas
-    double-buffers every pipelined operand — plus the un-double-buffered
-    scratch accumulator (PAS bin counters always; the pasm pooled
-    accumulator when the pool is fused).
+    Counts what sits in VMEM next to the image block, at Mosaic's tile
+    padding: the idx tile (uint8, 32-row tiles, halved when packed) and the
+    bias row (one 8-row tile), both double-buffered like the pooled output
+    block; the transposed patch tile the kernel assembles (``bk`` rows ×
+    ``pool²·bmp`` lanes); and the accumulators — the PAS bin scratch
+    (``bins + 1`` leaves room for the reserved pad bin) or, with a fused
+    pool, the pre-pool accumulator.  The codebook lives in SMEM.
     """
-    pw = pool * pool
-    idx = 2 * (bk // 2 if packed else bk) * bn
-    cb = 2 * (bins + 1) * 4
-    bias = 2 * bn * 4 if has_bias else 0
-    out = 2 * (bm // pw) * bn * 4
+    rows = pool * pool * bmp
+    idx = 2 * _round_up(bk // 2 if packed else bk, 32) * bn
+    bias = 2 * 8 * bn * 4 if has_bias else 0
+    out = 2 * bmp * bn * 4
+    tile = _round_up(bk, 8) * rows * itemsize
     if pas:
-        scratch = bm * bn * bins * 4
+        scratch = (bins + 1) * rows * bn * 4
     else:
-        scratch = bm * bn * 4 if pool > 1 else 0
-    return idx + cb + bias + out + scratch
+        scratch = rows * bn * 4 if pool > 1 else 0
+    return idx + bias + out + tile + scratch
+
+
+def _plan_rows(geom: ConvGeom, wq: int, rows_out: int, pas: bool) -> SlabPlan:
+    """The :class:`SlabPlan` with ``rows_out`` pooled output rows per slab:
+    blocks of ``bmp`` wide pixels (a lane multiple, at most 512 pre-pool
+    rows per block — 128 for the PAS bin scratch), and the lane halo the
+    largest tap offset reads past a slab's last block."""
+    S = geom.phase
+    pw = geom.pool * geom.pool
+    cap = LANE if pas else max(LANE, 512 // pw // LANE * LANE)
+    wide = rows_out * wq
+    bmp = min(cap, _round_up(wide, LANE))
+    n_blocks = -(-wide // bmp)
+    halo_lanes = LANE * (geom.max_offset(wq) // LANE + 1)
+    return SlabPlan(
+        n_slabs=-(-max(geom.ohp, 1) // rows_out), rows_out=rows_out,
+        band_rows=rows_out * S, halo_rows=max(geom.ky - geom.stride, 0),
+        wq=wq, bmp=bmp, n_blocks=n_blocks, lanes=n_blocks * bmp + halo_lanes,
+    )
+
+
+def _plan_vmem_bytes(geom: ConvGeom, plan: SlabPlan, **blocks) -> int:
+    """One grid step's VMEM: the double-buffered slab of phase images
+    (``c_in`` rows padded to the 8-sublane tile) plus every other block."""
+    itemsize = blocks.get("itemsize", 4)
+    image = 2 * geom.phase ** 2 * _round_up(geom.c_in, 8) * plan.lanes * itemsize
+    return image + _conv_block_vmem_bytes(bmp=plan.bmp, pool=geom.pool, **blocks)
 
 
 def conv_whole_image_fits(
-    geom: ConvGeom, hp: int, wp: int, *, bm: int, bn: int, bk: int, bins: int,
+    geom: ConvGeom, hp: int, wp: int, *, bn: int, bk: int, bins: int,
     packed: bool = False, pas: bool = False, has_bias: bool = True,
     vmem_budget: Optional[int] = None, itemsize: int = 4,
 ) -> bool:
     """Whether the whole padded image (``hp × wp``) stays VMEM-resident.
 
     THE accounting shared by :func:`conv_slab_plan` and ``conv2d``'s
-    :func:`repro.core.conv._implicit_fits` predicate: the image block counts
-    **twice** (Pallas prefetches image ``b+1`` across the batch grid
+    :func:`repro.core.conv._implicit_fits` predicate: the phase-layout image
+    counts **twice** (Pallas prefetches image ``b+1`` across the batch grid
     dimension — the double buffer is real VMEM) on top of every non-image
     per-grid-step block from :func:`_conv_block_vmem_bytes`.
     """
+    del hp  # the phase layout's size follows from the output rows
     budget = IMPLICIT_VMEM_BUDGET if vmem_budget is None else vmem_budget
-    fixed = _conv_block_vmem_bytes(bm=bm, bn=bn, bk=bk, bins=bins,
-                                   packed=packed, pas=pas, has_bias=has_bias,
-                                   pool=geom.pool)
-    return fixed + 2 * hp * geom.c_in * wp * itemsize <= budget
-
-
-def _halo_block_rows(band_rows: int, overlap: int) -> int:
-    """Halo block size: the smallest divisor of ``band_rows`` ≥ the needed
-    row overlap ``max(ky - stride, 0)`` (0 when no overlap).  Divisibility
-    makes the halo offset ``(slab+1)·band_rows`` block-aligned, which is all
-    the halo BlockSpec needs — ``band_rows`` itself stays unconstrained."""
-    if overlap <= 0:
-        return 0
-    d = overlap
-    while band_rows % d:
-        d += 1
-    return d
+    wq = -(-wp // geom.phase)
+    whole = _plan_rows(geom, wq, max(geom.ohp, 1), pas)
+    return _plan_vmem_bytes(
+        geom, whole, bn=bn, bk=bk, bins=bins, packed=packed, pas=pas,
+        has_bias=has_bias, itemsize=itemsize,
+    ) <= budget
 
 
 def conv_slab_plan(
-    geom: ConvGeom, hp: int, wp: int, *, bm: int, bn: int, bk: int, bins: int,
+    geom: ConvGeom, hp: int, wp: int, *, bn: int, bk: int, bins: int,
     packed: bool = False, pas: bool = False, has_bias: bool = True,
     vmem_budget: Optional[int] = None, itemsize: int = 4,
 ) -> SlabPlan:
-    """Size the row-band slab pipeline for one implicit conv (DESIGN.md §3.3).
+    """Size the implicit conv's image plan (DESIGN.md §3.3).
 
-    Whole image first: when the double-buffered image plus every non-image
-    block fits ``vmem_budget``, the plan is a single slab — the legacy
-    schedule, bit-for-bit (existing byte pins survive).  Otherwise the
-    padded image is tiled into the largest row bands whose double-buffered
-    footprint fits:
-
-    * a slab covers ``blocks_per_slab`` output-row blocks with
-      ``(blocks_per_slab·bmp) % owp == 0`` — whole pooled output rows, so
-      pool windows never straddle a seam and the band index map is a pure
-      division — giving ``band_rows = slab_out_rows·stride`` image rows;
-    * the minimal ``blocks_per_slab`` is ``owp / gcd(bmp, owp)`` (scaled up
-      until the band covers the ``ky - stride`` overlap); the planner then
-      grows it greedily in those multiples while the footprint fits;
-    * the halo block is :func:`_halo_block_rows`; ``rows_total`` is what the
-      kernel operand must carry.
-
-    Best-effort: when even the minimal slab exceeds the budget (or the
-    geometry is unsplittable — one slab would cover everything), the plan
-    degrades to the closest schedule rather than raising; the budget is a
-    sizing target, not a hard capacity.
+    Whole image first: when the double-buffered phase-layout image plus
+    every non-image block fits ``vmem_budget``, the plan is a single slab.
+    Otherwise the padded image is cut into the fewest row bands whose
+    footprint fits (``rows_out`` pooled output rows each, balanced so the
+    last band is not mostly padding).  Best-effort: when even one pooled
+    row per slab exceeds the budget, the plan takes the fewest slabs at
+    that smallest footprint — the budget is a sizing target, not a hard
+    capacity, and slabs that save no VMEM are never cut.
     """
     budget = IMPLICIT_VMEM_BUDGET if vmem_budget is None else vmem_budget
-    pw = geom.pool * geom.pool
-    bmp = bm // pw
-    n_blocks = max(1, -(-geom.P_out // bmp))
-    row_bytes = geom.c_in * wp * itemsize
-    whole = SlabPlan(1, n_blocks, hp, 0, hp)
-    if conv_whole_image_fits(geom, hp, wp, bm=bm, bn=bn, bk=bk, bins=bins,
-                             packed=packed, pas=pas, has_bias=has_bias,
-                             vmem_budget=budget, itemsize=itemsize):
-        return whole
-    fixed = _conv_block_vmem_bytes(bm=bm, bn=bn, bk=bk, bins=bins,
-                                   packed=packed, pas=pas, has_bias=has_bias,
-                                   pool=geom.pool)
-    overlap = max(geom.ky - geom.stride, 0)
-    owp = geom.owp
+    wq = -(-wp // geom.phase)
+    ohp = max(geom.ohp, 1)
+    blocks = dict(bn=bn, bk=bk, bins=bins, packed=packed, pas=pas,
+                  has_bias=has_bias, itemsize=itemsize)
 
-    def band(bps):  # image rows a bps-block slab advances by
-        return (bps * bmp // owp) * geom.pool * geom.stride
+    def foot(rows):
+        return _plan_vmem_bytes(geom, _plan_rows(geom, wq, rows, pas), **blocks)
 
-    bps_min = owp // math.gcd(bmp, owp)
-    if overlap > 0 and band(bps_min) < overlap:
-        bps_min *= -(-overlap // band(bps_min))
-    if bps_min >= n_blocks:
-        return whole  # unsplittable: one slab would already cover everything
-
-    def foot(bps):
-        s = band(bps)
-        return fixed + 2 * (s + _halo_block_rows(s, overlap)) * row_bytes
-
-    bps = bps_min
-    while bps + bps_min < n_blocks and foot(bps + bps_min) <= budget:
-        bps += bps_min
-    s = band(bps)
-    halo = _halo_block_rows(s, overlap)
-    n_slabs = -(-n_blocks // bps)
-    return SlabPlan(n_slabs, bps, s, halo, n_slabs * s + halo)
+    limit = max(budget, foot(1))
+    rows = ohp
+    while rows > 1 and foot(rows) > limit:
+        rows -= 1
+    n_slabs = -(-ohp // rows)
+    return _plan_rows(geom, wq, -(-ohp // n_slabs), pas)
 
 
 def _pad_weight_operands(idx, codebook, bn, gs_pad, packed):
@@ -360,7 +347,7 @@ def _pad_weight_operands(idx, codebook, bn, gs_pad, packed):
     representable), so padded positions are inert in both the fused-dequant
     and the PAS-histogram formulation — their paired activations are zero
     too (explicit path: zero-padded ``x`` rows; implicit path: the masked
-    :func:`~repro.kernels.pasm_matmul.patch_tile` gather).  When the pad bin
+    :func:`~repro.kernels.pasm_matmul.assemble_tile` mask).  When the pad bin
     is not representable (packed int4 at B=16, or B=256 saturating uint8)
     bin 0 is used instead — still exact, because the paired activations are
     zero.  Grouped codebooks pad per group so the kernel's
@@ -413,11 +400,11 @@ def _pad_operands(x, idx, codebook, bm, bn, gs_pad, packed):
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "packed", "logical_k", "gather", "interpret", "use_ref", "relu", "pool"
+        "packed", "logical_k", "interpret", "use_ref", "relu", "pool"
     ),
 )
 def _pasm_matmul_fwd_impl(
-    x, idx, codebook, bias=None, *, packed, logical_k, gather, interpret, use_ref,
+    x, idx, codebook, bias=None, *, packed, logical_k, interpret, use_ref,
     relu=False, pool=1,
 ):
     if use_ref:
@@ -444,7 +431,6 @@ def _pasm_matmul_fwd_impl(
         bm=bm,
         bn=bn,
         bk=bk,
-        gather=gather,
         relu=relu,
         pool=pool,
         interpret=interpret,
@@ -452,8 +438,8 @@ def _pasm_matmul_fwd_impl(
     return out[: M // (pool * pool), :N]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _pasm_matmul(x, idx, codebook, packed, gather, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _pasm_matmul(x, idx, codebook, packed, interpret):
     logical_k = x.shape[-1]
     return _pasm_matmul_fwd_impl(
         x,
@@ -461,17 +447,16 @@ def _pasm_matmul(x, idx, codebook, packed, gather, interpret):
         codebook,
         packed=packed,
         logical_k=logical_k,
-        gather=gather,
         interpret=interpret,
         use_ref=False,
     )
 
 
-def _pasm_fwd(x, idx, codebook, packed, gather, interpret):
-    return _pasm_matmul(x, idx, codebook, packed, gather, interpret), (x, idx, codebook)
+def _pasm_fwd(x, idx, codebook, packed, interpret):
+    return _pasm_matmul(x, idx, codebook, packed, interpret), (x, idx, codebook)
 
 
-def _pasm_bwd(packed, gather, interpret, res, g):
+def _pasm_bwd(packed, interpret, res, g):
     x, idx, codebook = res
     w = _ref.dequant_ref(idx, codebook, packed=packed).astype(x.dtype)
     dx = jnp.dot(g.astype(x.dtype), w.T)
@@ -492,8 +477,8 @@ def _pasm_bwd(packed, gather, interpret, res, g):
 _pasm_matmul.defvjp(_pasm_fwd, _pasm_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _pasm_matmul_ep(x, idx, codebook, bias, packed, gather, interpret, relu, pool):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _pasm_matmul_ep(x, idx, codebook, bias, packed, interpret, relu, pool):
     """The fused-epilogue variant: bias/ReLU (and the ``pool`` max-reduce
     over window-major rows) applied inside the kernel."""
     return _pasm_matmul_fwd_impl(
@@ -503,7 +488,6 @@ def _pasm_matmul_ep(x, idx, codebook, bias, packed, gather, interpret, relu, poo
         bias,
         packed=packed,
         logical_k=x.shape[-1],
-        gather=gather,
         interpret=interpret,
         use_ref=False,
         relu=relu,
@@ -511,15 +495,14 @@ def _pasm_matmul_ep(x, idx, codebook, bias, packed, gather, interpret, relu, poo
     )
 
 
-def _pasm_ep_fwd(x, idx, codebook, bias, packed, gather, interpret, relu, pool):
-    y = _pasm_matmul_ep(x, idx, codebook, bias, packed, gather, interpret, relu,
-                        pool)
+def _pasm_ep_fwd(x, idx, codebook, bias, packed, interpret, relu, pool):
+    y = _pasm_matmul_ep(x, idx, codebook, bias, packed, interpret, relu, pool)
     # y is a residual only for the ReLU mask (pool == 1: the pooled output
     # can't recover the pre-pool mask — the backward recomputes it instead)
     return y, (x, idx, codebook, bias, y if relu and pool == 1 else None)
 
 
-def _pasm_ep_bwd(packed, gather, interpret, relu, pool, res, g):
+def _pasm_ep_bwd(packed, interpret, relu, pool, res, g):
     x, idx, codebook, bias, y = res
     if pool > 1:
         # the fused forward never materializes the pre-pool activations —
@@ -535,7 +518,7 @@ def _pasm_ep_bwd(packed, gather, interpret, relu, pool, res, g):
         g, = vjp_post(g)
     elif relu:
         g = g * (y > 0).astype(g.dtype)  # mask through the fused ReLU
-    dx, _, dcb = _pasm_bwd(packed, gather, interpret, (x, idx, codebook), g)
+    dx, _, dcb = _pasm_bwd(packed, interpret, (x, idx, codebook), g)
     dbias = g.sum(axis=0).astype(bias.dtype)
     return dx, None, dcb, dbias
 
@@ -549,7 +532,6 @@ def pasm_matmul(
     *,
     bias: Optional[jax.Array] = None,
     relu: bool = False,
-    gather: str = "take",
     interpret: Optional[bool] = None,
     mesh=None,
     pool: int = 1,
@@ -585,12 +567,12 @@ def pasm_matmul(
             return _shard_gemm(
                 mesh, N,
                 lambda xl, il, cl, bl: _pasm_matmul_ep(
-                    xl, il, cl, bl, t.packed, gather, interpret, relu, pool
+                    xl, il, cl, bl, t.packed, interpret, relu, pool
                 ),
                 (x2, t.idx, t.codebook), x_rank=2, out_rank=2, bias=b,
             )
         return _pasm_matmul_ep(
-            x2, t.idx, t.codebook, b, t.packed, gather, interpret, relu, pool
+            x2, t.idx, t.codebook, b, t.packed, interpret, relu, pool
         )
     if mesh is not None:
         nd, _ = _mesh_sizes(mesh)
@@ -602,7 +584,7 @@ def pasm_matmul(
             y = _shard_gemm(
                 mesh, N,
                 lambda xl, il, cl: _pasm_matmul(
-                    xl, il, cl, t.packed, gather, interpret
+                    xl, il, cl, t.packed, interpret
                 ),
                 (x2, t.idx, t.codebook), x_rank=2, out_rank=2,
             )
@@ -611,17 +593,17 @@ def pasm_matmul(
             y = _shard_gemm(
                 mesh, N,
                 lambda xl, il, cl, bl: _pasm_matmul_ep(
-                    xl, il, cl, bl, t.packed, gather, interpret, relu, 1
+                    xl, il, cl, bl, t.packed, interpret, relu, 1
                 ),
                 (x2, t.idx, t.codebook), x_rank=2, out_rank=2, bias=b,
             )
         return y[:M].reshape(*lead, N)
     if bias is None and not relu:
-        y = _pasm_matmul(x2, t.idx, t.codebook, t.packed, gather, interpret)
+        y = _pasm_matmul(x2, t.idx, t.codebook, t.packed, interpret)
     else:
         b = jnp.zeros((N,), jnp.float32) if bias is None else bias
         y = _pasm_matmul_ep(
-            x2, t.idx, t.codebook, b, t.packed, gather, interpret, relu, 1
+            x2, t.idx, t.codebook, b, t.packed, interpret, relu, 1
         )
     return y.reshape(*lead, N)
 
@@ -750,76 +732,59 @@ def _geom_patches(x, geom: ConvGeom):
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "geom", "packed", "gather", "interpret", "relu", "use_pas",
-        "vmem_budget",
-    ),
+    static_argnames=("geom", "packed", "interpret", "relu", "use_pas",
+                     "vmem_budget"),
 )
 def _conv_fwd_impl(
-    x, idx, codebook, bias=None, *, geom, packed, gather="take", interpret=False,
+    x, idx, codebook, bias=None, *, geom, packed, interpret=False,
     relu=False, use_pas=False, vmem_budget=None,
 ):
-    """Shared implicit-conv forward: tile plan + weight padding + kernel call.
+    """Shared implicit-conv forward: tile plan + weight padding + image
+    relayout + kernel call.
 
     The reduction tiling (``bn``/``bk``/``gs_pad``) is a pure function of
     K/N/groups in :func:`_pick_blocks`, so the implicit kernel walks the
-    exact k-tile sequence of the explicit path — that is what makes it
-    bit-exact against explicit im2col.  Only ``bm`` differs: it is picked
-    from the *per-image* row count (the conv grid is per-image), so small-P
-    layers don't pad each image's output up to a batch-derived 128 rows.
-    ``geom.pool > 1`` switches the rows to window-major (``geom.P_rows``)
-    and aligns ``bm`` to whole pool windows — the k-tile sequence is
-    untouched, so the fused pool stays bit-exact vs conv + reduce_window.
-
-    Images whose double-buffered whole-image footprint exceeds
-    ``vmem_budget`` stream through the kernel as row-band slabs
-    (:func:`conv_slab_plan`): the padded image is sliced/zero-padded to the
-    plan's ``rows_total`` (sliced rows are provably never gathered — the
-    bottom band covers the last output row's receptive field; padded rows
-    are only replayed by clamped M-pad windows) and the kernel's image
-    operand becomes the double-buffered band(+halo) pair.  The GEMM
-    schedule is untouched, so slabbed output is bit-exact too.
+    exact k-tile sequence of the explicit path.  The padded image (NHWC
+    moved to channel-major first) is re-laid out into the plan's phase
+    images (:func:`~repro.kernels.pasm_matmul.phase_slabs`) — whole, or as
+    row-band slabs when the whole image would blow ``vmem_budget``
+    (:func:`conv_slab_plan`).  The kernel returns pooled *wide* pixels per
+    slab; the wide columns (``c ≥ owp``) and pad rows are dropped here.
     """
     G, _ = codebook.shape
     K = idx.shape[0] * (2 if packed else 1)
     N = idx.shape[1]
-    P = geom.P_rows
     gs = K // G
-    bm, bn, bk, gs_pad = _pick_blocks(P, K, N, gs, packed)
-    bm = _pool_bm(bm, geom.pool)
+    _, bn, bk, gs_pad = _pick_blocks(geom.P_rows, K, N, gs, packed)
     idxp, cbp, _ = _pad_weight_operands(idx, codebook, bn, gs_pad, packed)
     xp = _pad_image(x, geom)
-    rows_ax = 1 if geom.nhwc else 2
-    hp = xp.shape[rows_ax]
-    wp = xp.shape[2 if geom.nhwc else 3]
-    slab = conv_slab_plan(
-        geom, hp, wp, bm=bm, bn=bn, bk=bk, bins=codebook.shape[1],
+    if geom.nhwc:
+        xp = jnp.transpose(xp, (0, 3, 1, 2))
+    plan = conv_slab_plan(
+        geom, xp.shape[2], xp.shape[3], bn=bn, bk=bk, bins=codebook.shape[1],
         packed=packed, pas=use_pas, has_bias=bias is not None,
-        vmem_budget=vmem_budget,
+        vmem_budget=vmem_budget, itemsize=xp.dtype.itemsize,
     )
-    if slab.n_slabs > 1 and slab.rows_total != hp:
-        if slab.rows_total < hp:
-            xp = jax.lax.slice_in_dim(xp, 0, slab.rows_total, axis=rows_ax)
-        else:
-            cfg = [(0, 0)] * 4
-            cfg[rows_ax] = (0, slab.rows_total - hp)
-            xp = jnp.pad(xp, cfg)
+    xs = phase_slabs(xp, geom, plan)
     bias_row = None
     if bias is not None:
         bias_row = jnp.pad(bias.astype(jnp.float32), (0, idxp.shape[1] - N))
         bias_row = bias_row.reshape(1, -1)
     if use_pas:
         out = pas_conv_kernel_call(
-            xp, idxp, cbp, bias_row, geom=geom, gs=gs, gs_pad=gs_pad,
-            bm=bm, bn=bn, bk=bk, relu=relu, slab=slab, interpret=interpret,
+            xs, idxp, cbp, bias_row, geom=geom, plan=plan, gs=gs,
+            gs_pad=gs_pad, bn=bn, bk=bk, relu=relu, interpret=interpret,
         )
     else:
         out = pasm_conv_kernel_call(
-            xp, idxp, cbp, bias_row, geom=geom, packed=packed, gs=gs,
-            gs_pad=gs_pad, bm=bm, bn=bn, bk=bk, gather=gather, relu=relu,
-            slab=slab, interpret=interpret,
+            xs, idxp, cbp, bias_row, geom=geom, plan=plan, packed=packed,
+            gs=gs, gs_pad=gs_pad, bn=bn, bk=bk, relu=relu,
+            interpret=interpret,
         )
-    return out[:, : geom.P_out, :N]
+    B = x.shape[0]
+    out = out[:, :, : plan.rows_out * plan.wq, :N]
+    out = out.reshape(B, plan.n_slabs * plan.rows_out, plan.wq, N)
+    return out[:, : geom.ohp, : geom.owp].reshape(B, geom.P_out, N)
 
 
 def _pool_rowmajor_ref(y, geom, batch):
@@ -838,7 +803,7 @@ def _pool_rowmajor_ref(y, geom, batch):
     return yb.max(axis=(2, 4)).reshape(batch * geom.P_out, N)
 
 
-def _conv_bwd_core(geom, packed, gather, interpret, relu, res, g):
+def _conv_bwd_core(geom, packed, interpret, relu, res, g):
     """Backward through the implicit conv via explicit col2im (initial scope):
     materialize patches, reuse the GEMM VJP, scatter back through im2colᵀ.
 
@@ -868,30 +833,28 @@ def _conv_bwd_core(geom, packed, gather, interpret, relu, res, g):
         g2, = vjp_post(g2)
     elif relu:
         g2 = g2 * (y.reshape(g2.shape) > 0).astype(g2.dtype)
-    dp, _, dcb = _pasm_bwd(packed, gather, interpret, (patches, idx, codebook), g2)
+    dp, _, dcb = _pasm_bwd(packed, interpret, (patches, idx, codebook), g2)
     dx, = vjp_patch(dp[:, : geom.conv_k])
     return dx, dcb, g2
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _pasm_conv(x, idx, codebook, geom, packed, gather, interpret, vmem_budget):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _pasm_conv(x, idx, codebook, geom, packed, interpret, vmem_budget):
     return _conv_fwd_impl(
-        x, idx, codebook, geom=geom, packed=packed, gather=gather,
-        interpret=interpret, vmem_budget=vmem_budget,
+        x, idx, codebook, geom=geom, packed=packed, interpret=interpret,
+        vmem_budget=vmem_budget,
     )
 
 
-def _pasm_conv_fwd(x, idx, codebook, geom, packed, gather, interpret,
-                   vmem_budget):
-    y = _pasm_conv(x, idx, codebook, geom, packed, gather, interpret,
-                   vmem_budget)
+def _pasm_conv_fwd(x, idx, codebook, geom, packed, interpret, vmem_budget):
+    y = _pasm_conv(x, idx, codebook, geom, packed, interpret, vmem_budget)
     return y, (x, idx, codebook)
 
 
-def _pasm_conv_bwd(geom, packed, gather, interpret, vmem_budget, res, g):
+def _pasm_conv_bwd(geom, packed, interpret, vmem_budget, res, g):
     x, idx, codebook = res
     dx, dcb, _ = _conv_bwd_core(
-        geom, packed, gather, interpret, False, (x, idx, codebook, None, None), g
+        geom, packed, interpret, False, (x, idx, codebook, None, None), g
     )
     return dx, None, dcb
 
@@ -899,30 +862,29 @@ def _pasm_conv_bwd(geom, packed, gather, interpret, vmem_budget, res, g):
 _pasm_conv.defvjp(_pasm_conv_fwd, _pasm_conv_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _pasm_conv_ep(x, idx, codebook, bias, geom, packed, gather, interpret,
-                  relu, vmem_budget):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _pasm_conv_ep(x, idx, codebook, bias, geom, packed, interpret, relu,
+                  vmem_budget):
     """The fused-epilogue implicit conv: bias/ReLU applied inside the kernel."""
     return _conv_fwd_impl(
-        x, idx, codebook, bias, geom=geom, packed=packed, gather=gather,
-        interpret=interpret, relu=relu, vmem_budget=vmem_budget,
+        x, idx, codebook, bias, geom=geom, packed=packed, interpret=interpret,
+        relu=relu, vmem_budget=vmem_budget,
     )
 
 
-def _pasm_conv_ep_fwd(x, idx, codebook, bias, geom, packed, gather, interpret,
-                      relu, vmem_budget):
-    y = _pasm_conv_ep(x, idx, codebook, bias, geom, packed, gather, interpret,
-                      relu, vmem_budget)
+def _pasm_conv_ep_fwd(x, idx, codebook, bias, geom, packed, interpret, relu,
+                      vmem_budget):
+    y = _pasm_conv_ep(x, idx, codebook, bias, geom, packed, interpret, relu,
+                      vmem_budget)
     # y is a residual only for the ReLU mask (and only when unpooled — the
     # pooled output can't recover the pre-pool mask; the backward recomputes)
     return y, (x, idx, codebook, bias, y if relu and geom.pool == 1 else None)
 
 
-def _pasm_conv_ep_bwd(geom, packed, gather, interpret, relu, vmem_budget, res,
-                      g):
+def _pasm_conv_ep_bwd(geom, packed, interpret, relu, vmem_budget, res, g):
     x, idx, codebook, bias, y = res
     dx, dcb, g2 = _conv_bwd_core(
-        geom, packed, gather, interpret, relu, (x, idx, codebook, bias, y), g
+        geom, packed, interpret, relu, (x, idx, codebook, bias, y), g
     )
     dbias = g2.sum(axis=0).astype(bias.dtype)
     return dx, None, dcb, dbias
@@ -938,7 +900,6 @@ def pasm_conv2d(
     *,
     bias: Optional[jax.Array] = None,
     relu: bool = False,
-    gather: str = "take",
     interpret: Optional[bool] = None,
     mesh=None,
     vmem_budget: Optional[int] = None,
@@ -946,26 +907,26 @@ def pasm_conv2d(
 ) -> jax.Array:
     """Implicit-GEMM conv on the fused-dequant kernel: ``(B, img) → (B, P, N)``.
 
-    One ``pallas_call`` over the (spatially padded) image batch — the im2col
-    patch tiles are assembled inside the kernel, so no ``(B·P, K)`` patch
-    matrix exists in HBM.  ``bias (N,)`` / ``relu`` fuse into the last-k-step
-    write-through exactly as in :func:`pasm_matmul`, and ``geom.pool > 1``
-    additionally max-reduces each ``(pool, pool)`` output window there — the
-    whole conv/ReLU/pool stage is ONE pallas_call and the pre-pool
-    activations never touch HBM.  Differentiable in ``x``, ``t.codebook``
-    and ``bias`` (the backward pass materializes patches explicitly — col2im
-    — and recomputes the pre-pool map for the argmax routing, for now).
-    Pool windows live inside single images, so the fused pool shards over
-    ``data`` unchanged.  With ``mesh=`` the image batch
+    One ``pallas_call`` over the (spatially padded, phase-laid-out) image
+    batch — the im2col patch tiles are assembled inside the kernel, so no
+    ``(B·P, K)`` patch matrix exists in HBM.  ``bias (N,)`` / ``relu`` fuse
+    into the last-k-step write-through exactly as in :func:`pasm_matmul`,
+    and ``geom.pool > 1`` additionally max-reduces each ``(pool, pool)``
+    output window there — the whole conv/ReLU/pool stage is ONE pallas_call
+    and the pre-pool activations never touch HBM.  Differentiable in ``x``,
+    ``t.codebook`` and ``bias`` (the backward pass materializes patches
+    explicitly — col2im — and recomputes the pre-pool map for the argmax
+    routing, for now).  Pool windows live inside single images, so the fused
+    pool shards over ``data`` unchanged.  With ``mesh=`` the image batch
     shards over ``data`` (the batch must already divide the axis — the
     ``conv2d`` front-end pads uneven remainders) and N over ``model`` when
     divisible; each shard derives its tile plan from the local shapes, and
     ``gather_output=True`` (the default) all-gathers N inside the sharded
     body so the returned activations are model-replicated — consecutive
     sharded conv layers see no XLA resharding between their pallas_calls.
-    ``vmem_budget`` bounds the per-slab image footprint: images whose
+    ``vmem_budget`` bounds the per-step image footprint: images whose
     double-buffered whole-image residency would blow the budget stream as
-    row-band slabs (:func:`conv_slab_plan`), bit-exact vs whole-image.
+    row-band slabs (:func:`conv_slab_plan`).
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -980,7 +941,7 @@ def pasm_conv2d(
             return _shard_gemm(
                 mesh, t.shape[1],
                 lambda xl, il, cl: _pasm_conv(
-                    xl, il, cl, geom, t.packed, gather, interpret, vmem_budget
+                    xl, il, cl, geom, t.packed, interpret, vmem_budget
                 ),
                 (x, t.idx, t.codebook), x_rank=4, out_rank=3,
                 gather_output=gather_output,
@@ -989,8 +950,7 @@ def pasm_conv2d(
         return _shard_gemm(
             mesh, t.shape[1],
             lambda xl, il, cl, bl: _pasm_conv_ep(
-                xl, il, cl, bl, geom, t.packed, gather, interpret, relu,
-                vmem_budget,
+                xl, il, cl, bl, geom, t.packed, interpret, relu, vmem_budget,
             ),
             (x, t.idx, t.codebook), x_rank=4, out_rank=3, bias=b,
             gather_output=gather_output,
@@ -999,12 +959,11 @@ def pasm_conv2d(
     # pooled (argmax-routed) backward
     if bias is None and not relu and geom.pool == 1:
         return _pasm_conv(
-            x, t.idx, t.codebook, geom, t.packed, gather, interpret, vmem_budget
+            x, t.idx, t.codebook, geom, t.packed, interpret, vmem_budget
         )
     b = jnp.zeros((t.shape[1],), jnp.float32) if bias is None else bias
     return _pasm_conv_ep(
-        x, t.idx, t.codebook, b, geom, t.packed, gather, interpret, relu,
-        vmem_budget,
+        x, t.idx, t.codebook, b, geom, t.packed, interpret, relu, vmem_budget,
     )
 
 
@@ -1112,13 +1071,14 @@ def conv_hbm_bytes(
     activation term is twice the padded patch-matrix bytes, inflating input
     traffic by up to ``ky·kx/stride²`` over the raw image.
 
-    ``implicit=True``: the padded image streams once per reuse window (each
-    image block or row-band slab stays VMEM-resident across its whole tile
-    loop), so the activation term is the slab plan's **fetched rows**
-    (:attr:`SlabPlan.fetched_rows` — the padded image bytes when the whole
-    image fits ``vmem_budget`` double-buffered, else ``n_slabs·(band+halo)``
-    rows, the halo re-fetched once per seam).  Weight/codebook/output terms
-    follow the same padded-operand accounting as :func:`pasm_hbm_bytes`.
+    ``implicit=True``: the kernel streams its phase-layout image operand
+    once (each whole image or row-band slab stays VMEM-resident across its
+    whole tile loop), so the activation term is the plan's operand size
+    (:meth:`SlabPlan.image_elems` — the padded image plus lane/phase
+    alignment padding, and each slab's halo rows when the image streams as
+    slabs), and the store is the pooled *wide* pixel blocks the kernel
+    writes.  Weight/codebook terms follow the same padded-operand
+    accounting as :func:`pasm_hbm_bytes`.
     The logical-shape (plan-free) counterpart is
     :func:`repro.core.hwmodel.conv_hbm_traffic`.
 
@@ -1158,11 +1118,12 @@ def conv_hbm_bytes(
         (plh, phh), (plw, phw) = geom.pad
         hp, wp = ih + plh + phh, iw + plw + phw
         plan = conv_slab_plan(
-            geom, hp, wp, bm=bm, bn=bn, bk=bk, bins=B, packed=t.packed,
+            geom, hp, wp, bn=bn, bk=bk, bins=B, packed=t.packed,
             pas=False, has_bias=True, vmem_budget=vmem_budget,
+            itemsize=act_bytes,
         )
-        x_bytes = batch * geom.c_in * plan.fetched_rows * wp * act_bytes
-        out_bytes = batch * _round_up(geom.P_out, bm // pw) * Np * 4
+        x_bytes = batch * plan.image_elems(geom) * act_bytes
+        out_bytes = batch * plan.n_slabs * plan.n_blocks * plan.bmp * Np * 4
     else:
         Mp = _round_up(batch * P, bm)
         x_bytes = 2 * Mp * Kp * act_bytes  # im2col store + kernel stream

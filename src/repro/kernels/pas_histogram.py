@@ -4,26 +4,28 @@ This is the literal TPU mapping of the PASM circuit (paper §2.2):
 
   PAS phase    — per k-tile, image values are accumulated into ``B`` bin
                  accumulators that live in a VMEM scratch block
-                 (``S[m, n, b] += x[m, k]·[idx[k, n] = b]``); the bin
+                 (``S[b, m, n] += x[m, k]·[idx[k, n] = b]``); the bin
                  accumulators are the VMEM analogue of the PAS register file.
   post-pass    — at the *last* k step only, one multiply per bin folds the
-                 codebook in: ``y[m, n] = Σ_b S[m, n, b]·cb[b]`` — the
+                 codebook in: ``y[m, n] = Σ_b S[b, m, n]·cb[b]`` — the
                  "shared post-pass MAC" of the paper, amortized over the
-                 whole reduction.
+                 whole reduction.  The bins fold in ascending ``b`` order in
+                 every kernel here, so explicit and implicit engines round
+                 identically.
 
-The PAS phase is expressed as ``x_tile @ one_hot(idx_tile)`` so it runs on
-the MXU, but the one-hot expansion makes it cost ``B×`` the MACs of a direct
-product — on a fixed systolic array the paper's gate-level win does not
-transfer (DESIGN.md §2).  This kernel exists to (a) demonstrate the faithful
-formulation end-to-end, (b) let benchmarks *measure* that trade-off against
-``pasm_matmul`` instead of assuming it.
+The PAS phase is one MXU matmul per bin, ``x_tile @ [idx_tile = b]``, so it
+costs ``B×`` the MACs of a direct product — on a fixed systolic array the
+paper's gate-level win does not transfer (DESIGN.md §2).  This kernel exists
+to (a) demonstrate the faithful formulation end-to-end, (b) let benchmarks
+*measure* that trade-off against ``pasm_matmul`` instead of assuming it.
 
-VMEM budget: scratch ``(bm, bn, B)`` f32 = 128·128·16·4 = 1 MiB at defaults.
+VMEM budget: scratch ``(B, bm, bn)`` f32 = 16·128·128·4 = 1 MiB at defaults
+(bins lead, so no bin count is ever padded to a lane tile).
 
 :func:`pas_conv_kernel_call` is the implicit-GEMM conv variant: the ``x``
-operand is the raw padded image batch and the ``(bm, bk)`` patch tile is
-assembled in VMEM by :func:`repro.kernels.pasm_matmul.patch_tile` — same PAS
-phase and post-pass, no ``(B·P, K)`` patch matrix in HBM.
+operand is the phase-layout image batch and the patch tile is assembled in
+VMEM by :func:`repro.kernels.pasm_matmul.assemble_tile` — same PAS phase and
+post-pass, no ``(B·P, K)`` patch matrix in HBM.
 """
 from __future__ import annotations
 
@@ -34,57 +36,46 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 from repro.kernels.pasm_matmul import (
     ConvGeom,
     SlabPlan,
-    _image_specs,
-    _slab_image,
-    patch_tile,
+    _dot,
+    _params,
+    assemble_tile,
+    conv_image_spec,
+    conv_out_spec,
+    epilogue_store,
 )
-from repro.kernels.ref import max_pool_rows
 
 __all__ = ["pas_matmul_kernel_call", "pas_conv_kernel_call"]
 
 
 def _pas_step(
-    x_tile, idx_ref, cb_ref, b_ref, o_ref, s_ref, *, k, n_k: int, bins: int,
-    relu: bool, pool: int = 1,
+    lhs, idx_ref, cb_ref, b_ref, o_ref, s_ref, *, k, n_k: int, relu: bool,
+    pool: int = 1, transposed: bool = False,
 ):
     """The shared per-k-step body of BOTH entry points: PAS-phase one-hot
     accumulate into the VMEM bin scratch, then the post-pass multiply (plus
-    the fused bias/ReLU epilogue) at the last k step only.  ``o_ref`` may
-    carry a leading length-1 batch axis (the conv grid).  ``pool > 1``
-    max-reduces each group of ``pool²`` window-major rows in the post-pass
-    write-through (the fused max-pool epilogue) — the bin scratch already
-    holds the whole pre-pool block, so no extra accumulator is needed."""
-    idx = idx_ref[...]  # (bk, bn)
-    bm, bk = x_tile.shape
-    bn = idx.shape[1]
-    # PAS phase: one-hot selection network. (bk, bn, B) → (bk, bn·B) so the
-    # accumulate runs as a single MXU matmul per tile.
-    onehot = (idx[:, :, None] == jax.lax.broadcasted_iota(jnp.uint8, (1, 1, bins), 2))
-    onehot = onehot.astype(x_tile.dtype).reshape(bk, bn * bins)
-    s_ref[...] += jnp.dot(x_tile, onehot, preferred_element_type=jnp.float32).reshape(
-        bm, bn, bins
-    )
+    the fused bias/ReLU/max-pool epilogue) at the last k step only.
+    ``o_ref`` may carry leading length-1 axes (the conv grid)."""
+    idx = idx_ref[...].astype(jnp.int32)  # Mosaic compares in 32 bits
+    bins = s_ref.shape[0]
+    for b in range(bins):
+        s_ref[b] += _dot(lhs, (idx == b).astype(lhs.dtype), transposed)
 
     # post-pass multiply: executed once, after all accumulation — B multiplies
     # per output element instead of K.  The bias/ReLU epilogue rides the same
     # write-through (the paper's shared post-pass MAC carries the bias too).
     @pl.when(k == n_k - 1)
     def _postpass():
-        cb = cb_ref[0].astype(jnp.float32)  # (B,)
-        y = jnp.einsum("mnb,b->mn", s_ref[...], cb)
-        if b_ref is not None:
-            y = y + b_ref[...]  # (1, bn) broadcasts over rows
-        if relu:
-            y = jnp.maximum(y, 0.0)
-        o_ref[...] = max_pool_rows(y, pool).reshape(o_ref.shape)
+        y = s_ref[0] * cb_ref[0, 0]
+        for b in range(1, bins):
+            y = y + s_ref[b] * cb_ref[0, b]
+        epilogue_store(y, b_ref, o_ref, relu=relu, pool=pool,
+                       transposed=transposed)
 
 
-def _kernel(x_ref, idx_ref, cb_ref, *rest, bins: int, n_k: int, relu: bool,
-            pool: int):
+def _kernel(x_ref, idx_ref, cb_ref, *rest, n_k: int, relu: bool, pool: int):
     b_ref, o_ref, s_ref = rest if len(rest) == 3 else (None, *rest)
     k = pl.program_id(2)
 
@@ -94,7 +85,7 @@ def _kernel(x_ref, idx_ref, cb_ref, *rest, bins: int, n_k: int, relu: bool,
 
     _pas_step(
         x_ref[...], idx_ref, cb_ref, b_ref, o_ref, s_ref,
-        k=k, n_k=n_k, bins=bins, relu=relu, pool=pool,
+        k=k, n_k=n_k, relu=relu, pool=pool,
     )
 
 
@@ -130,54 +121,46 @@ def pas_matmul_kernel_call(
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
         pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-        pl.BlockSpec((1, B), lambda i, j, k: (0, 0)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
-    operands = [x, idx, codebook]
+    operands = [x, idx, codebook.astype(jnp.float32)]
     if bias is not None:
         assert bias.shape == (1, N), bias.shape
         in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k: (0, j)))
         operands.append(bias)
 
     return pl.pallas_call(
-        functools.partial(_kernel, bins=B, n_k=n_k, relu=relu, pool=pool),
+        functools.partial(_kernel, n_k=n_k, relu=relu, pool=pool),
         grid=(M // bm, N // bn, n_k),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm // pw, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M // pw, N), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn, B), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        scratch_shapes=[pltpu.VMEM((B, bm, bn), jnp.float32)],
+        compiler_params=_params(3),
         interpret=interpret,
     )(*operands)
 
 
 def _conv_kernel(
-    x_ref, *refs, geom: ConvGeom, bins: int, n_k: int,
-    relu: bool, bm: int, bk: int, gs: int, gs_pad: int, slab=None,
+    x_ref, idx_ref, cb_ref, *rest, geom: ConvGeom, plan: SlabPlan,
+    n_k: int, relu: bool, bk: int, gs: int, gs_pad: int,
 ):
-    """Implicit-GEMM body: gather the patch tile instead of reading an
-    explicit x block, then the same :func:`_pas_step`."""
-    if slab is not None and slab.halo_rows:
-        halo_ref, refs = refs[0], refs[1:]
-    else:
-        halo_ref = None
-    idx_ref, cb_ref, *rest = refs
-    b_ref, o_ref, s_ref = rest if len(rest) == 3 else (None, *rest)
+    """Implicit-GEMM body: assemble the transposed patch tile, then the same
+    :func:`_pas_step` as the explicit GEMM."""
+    *rest, t_ref, s_ref = rest
+    b_ref, o_ref = rest if len(rest) == 2 else (None, rest[0])
     k = pl.program_id(3)
 
     @pl.when(k == 0)
     def _zero():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    img, row0 = _slab_image(x_ref, halo_ref, geom, slab)
-    patch = patch_tile(
-        img, pl.program_id(1) * bm, k * bk,
-        geom=geom, bm=bm, bk=bk, gs=gs, gs_pad=gs_pad, row0=row0,
-    )
+    base = (pl.program_id(1) % plan.n_blocks) * plan.bmp
+    assemble_tile(x_ref, t_ref, base, k * bk, geom=geom, plan=plan, bk=bk,
+                  gs=gs, gs_pad=gs_pad)
     _pas_step(
-        patch, idx_ref, cb_ref, b_ref, o_ref, s_ref,
-        k=k, n_k=n_k, bins=bins, relu=relu, pool=geom.pool,
+        t_ref[:bk, :], idx_ref, cb_ref, b_ref, o_ref, s_ref,
+        k=k, n_k=n_k, relu=relu, pool=geom.pool, transposed=True,
     )
 
 
@@ -188,44 +171,36 @@ def pas_conv_kernel_call(
     bias: "jax.Array | None" = None,
     *,
     geom: ConvGeom,
+    plan: SlabPlan,
     gs: int,
     gs_pad: int,
-    bm: int = 128,
     bn: int = 128,
     bk: int = 512,
     relu: bool = False,
-    slab: "SlabPlan | None" = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Implicit-GEMM conv on the paper-faithful two-phase formulation.
 
-    ``x (B, img...)`` padded per ``geom`` · ``idx (Kp, Np)`` · ``codebook
-    (1, B)`` → ``(B, Pp, Np) f32`` (real rows sliced by the caller; pooled
-    when ``geom.pool > 1``, the fused max-pool epilogue riding the
-    post-pass).  ``slab`` streams the image as double-buffered row bands
-    exactly as in :func:`~repro.kernels.pasm_matmul.pasm_conv_kernel_call`.
-    Single dictionary only, like :func:`pas_matmul_kernel_call`.
+    Operands and output as :func:`~repro.kernels.pasm_matmul.
+    pasm_conv_kernel_call` (phase-layout image in, pooled wide pixels out;
+    the fused max-pool rides the post-pass).  Single dictionary only, like
+    :func:`pas_matmul_kernel_call`.
     """
-    B_img = x.shape[0]
+    B_img, n_slabs, _, c_in, _ = x.shape
     G, B = codebook.shape
     assert G == 1, "PAS-formulation kernel is paper-faithful: one dictionary"
     Np = idx.shape[1]
     Kp = idx.shape[0]
     assert Kp == gs_pad and gs_pad % bk == 0, (Kp, gs_pad, bk)
-    pw = geom.pool * geom.pool
-    assert bm % pw == 0, (bm, geom.pool)
-    bmp = bm // pw  # stored (pooled) rows per block
+    rows = geom.pool * geom.pool * plan.bmp
     n_k = Kp // bk
-    Pp = (geom.P_out + bmp - 1) // bmp * bmp
-    if slab is not None and slab.n_slabs == 1:
-        slab = None  # single slab ≡ the legacy whole-image schedule
 
-    img_specs, operands = _image_specs(x, geom, slab)
-    in_specs = img_specs + [
+    in_specs = [
+        conv_image_spec(geom, plan, c_in),
         pl.BlockSpec((bk, bn), lambda b, i, j, k: (k, j)),
-        pl.BlockSpec((1, B), lambda b, i, j, k: (0, 0)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
-    operands = operands + [idx, codebook]
+    operands = [x, idx, codebook.astype(jnp.float32)]
     if bias is not None:
         assert bias.shape == (1, Np), bias.shape
         in_specs.append(pl.BlockSpec((1, bn), lambda b, i, j, k: (0, j)))
@@ -233,16 +208,18 @@ def pas_conv_kernel_call(
 
     return pl.pallas_call(
         functools.partial(
-            _conv_kernel, geom=geom, bins=B, n_k=n_k, relu=relu,
-            bm=bm, bk=bk, gs=gs, gs_pad=gs_pad, slab=slab,
+            _conv_kernel, geom=geom, plan=plan, n_k=n_k, relu=relu,
+            bk=bk, gs=gs, gs_pad=gs_pad,
         ),
-        grid=(B_img, Pp // bmp, Np // bn, n_k),
+        grid=(B_img, n_slabs * plan.n_blocks, Np // bn, n_k),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bmp, bn), lambda b, i, j, k: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((B_img, Pp, Np), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn, B), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-        ),
+        out_specs=conv_out_spec(plan, bn),
+        out_shape=jax.ShapeDtypeStruct(
+            (B_img, n_slabs, plan.n_blocks * plan.bmp, Np), jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((-(-bk // 8) * 8, rows), x.dtype),
+            pltpu.VMEM((B, rows, bn), jnp.float32),
+        ],
+        compiler_params=_params(4),
         interpret=interpret,
     )(*operands)

@@ -10,15 +10,17 @@ the bandwidth-bound regimes (decode serving) where weights dominate bytes.
 Tiling: grid ``(M/bm, N/bn, K/bk)`` with the reduction innermost; a VMEM
 f32 accumulator block is zeroed at ``k==0`` and written through at the last
 ``k`` step — where the optional bias-add/ReLU epilogue is fused, so a conv
-layer with bias+activation is a single ``pallas_call`` (no XLA epilogue).  Block shapes are MXU-aligned (multiples of 128 on N, 8/128 on
-M/K per dtype tiling).  The codebook block is ``(1, B)`` — ≤ 1 KiB, resident
-in VMEM for the whole tile loop; group selection is an index-map function of
-``k`` (requires ``group_size % bk == 0``).
+layer with bias+activation is a single ``pallas_call`` (no XLA epilogue).
+Block shapes are MXU-aligned (multiples of 128 on N, 8/128 on M/K per dtype
+tiling).  The whole ``(G, B)`` codebook sits in SMEM for the entire grid
+(a few hundred scalars); the k-tile's group is ``k // (group_size/bk)``
+(requires ``group_size % bk == 0``).
 
-Weight gather strategies (``gather=``):
-  * ``"take"``    — vector gather from the VMEM codebook (default).
-  * ``"onehot"``  — ``one_hot(idx) @ codebook``: guaranteed Mosaic lowering on
-                    older toolchains, costs B extra VPU ops per element.
+Dequantization is the one-hot select ``w = Σ_b cb[b]·[idx = b]`` — the PAS
+selection network in vectorized form: ``B`` int32 compares + selects per
+weight on the VPU, with the codebook entries read as SMEM scalars (Mosaic
+has no general vector gather, and refuses sub-32-bit compares/shifts, so
+indices widen to int32 before any arithmetic).
 
 Two entry points share the kernel body:
 
@@ -26,12 +28,11 @@ Two entry points share the kernel body:
     ``(M, K)`` operand (the conv path materializes an im2col patch matrix in
     HBM first).
   * :func:`pasm_conv_kernel_call` — **implicit-GEMM convolution**: ``x`` is
-    the raw (spatially padded) image batch; each ``(bm, bk)`` patch tile is
-    assembled *inside* the kernel from the VMEM-resident image
-    (:func:`patch_tile`), so no ``(B·P, K)`` patch matrix ever exists in HBM.
-    The grid grows a leading batch dimension and the output is per-image
-    ``(B, P, N)``.  Identical tile plan + accumulation order ⇒ bit-exact
-    with the explicit path (asserted in tests/test_conv_implicit.py).
+    the image batch in the *phase layout* (:func:`phase_slabs`); each patch
+    tile is assembled *inside* the kernel (:func:`assemble_tile`), so no
+    ``(B·P, K)`` patch matrix ever exists in HBM.  The k-tile plan is the
+    explicit path's, so every output sees the same dequantized weight tiles
+    in the same k order.
 """
 from __future__ import annotations
 
@@ -43,11 +44,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 from repro.kernels.ref import max_pool_rows
 
 __all__ = ["pasm_matmul_kernel_call", "pasm_conv_kernel_call", "ConvGeom",
-           "SlabPlan", "patch_tile"]
+           "SlabPlan", "assemble_tile", "phase_slabs"]
+
+LANE = 128
+# Scoped-VMEM ceiling handed to Mosaic for every kernel here: the slab
+# planner sizes the conv kernels' blocks against a smaller budget
+# (``ops.IMPLICIT_VMEM_BUDGET``), this only lifts Mosaic's 16 MiB default so
+# its own temporaries fit next to them (a v5e core has 128 MiB of VMEM).
+VMEM_LIMIT = 64 * 1024 * 1024
 
 
 class ConvGeom(NamedTuple):
@@ -56,11 +63,9 @@ class ConvGeom(NamedTuple):
     Built by :func:`repro.core.conv.conv_geom`; hashable so it rides jit
     static args and ``custom_vjp`` nondiff args.  ``pad`` is the spatial
     zero-pad already applied to the image the kernel sees
-    (``((lo_h, hi_h), (lo_w, hi_w))`` — SAME windowing happens *outside*,
-    the kernel only ever gathers in-bounds).  ``pool > 1`` fuses a
-    non-overlapping ``(pool, pool)`` max-pool into the kernel epilogue:
-    GEMM rows switch to **window-major** order (each consecutive ``pool²``
-    rows are one pool window) and the output is the pooled ``P_out`` map —
+    (``((lo_h, hi_h), (lo_w, hi_w))`` — SAME windowing happens *outside*).
+    ``pool > 1`` fuses a non-overlapping ``(pool, pool)`` max-pool into the
+    kernel epilogue: the output is the pooled ``P_out`` map and the
     pre-pool activations never leave VMEM (DESIGN.md §3.2).
     """
 
@@ -106,184 +111,145 @@ class ConvGeom(NamedTuple):
         ``pool == 1``)."""
         return self.P_out * self.pool * self.pool
 
+    @property
+    def phase(self) -> int:
+        """Phase factor ``S = stride·pool`` of the kernel's image layout."""
+        return self.stride * self.pool
+
+    def max_offset(self, wq: int) -> int:
+        """Largest flat lane offset a (window, tap) pair reads past its
+        output pixel in the phase layout of row pitch ``wq``."""
+        S = self.phase
+        ey = (self.pool - 1) * self.stride + self.ky - 1
+        ex = (self.pool - 1) * self.stride + self.kx - 1
+        return (ey // S) * wq + ex // S
+
 
 class SlabPlan(NamedTuple):
-    """Row-band slab pipeline plan for the implicit-GEMM conv engines.
+    """The implicit conv's image plan: phase layout, pixel blocks and slabs.
 
     Built by :func:`repro.kernels.ops.conv_slab_plan`; hashable so it rides
-    jit static args.  ``n_slabs == 1`` is the legacy whole-image-resident
-    schedule (one image block per grid step, no halo operand).  With
-    ``n_slabs > 1`` the padded image streams through VMEM as **row bands**:
-    the kernel's x operand becomes a ``band_rows``-row block whose index map
-    advances every ``blocks_per_slab`` output-row blocks, plus (when the
-    conv window overlaps band seams, ``ky > stride``) a second ``halo_rows``
-    block of the SAME array covering the first rows of the next band.
-    Pallas's built-in block pipeline then double-buffers the next band while
-    the current one computes — the slab DMA overlaps patch assembly with no
-    manual async copies, and revisited block indices are never refetched.
+    jit static args.  The padded image is re-laid out (by XLA, outside the
+    kernel) into ``S² = (stride·pool)²`` *phase images* — phase ``(py, px)``
+    holds rows ``py::S`` and columns ``px::S`` — each flattened row-major to
+    one lane axis of pitch ``wq = ceil(wp / S)``.  Every (window offset,
+    tap) then reads a unit-stride lane window of one phase image: pooled
+    output pixel ``(r, c)`` is lane ``r·wq + c`` (the *wide* pixel index;
+    lanes with ``c ≥ owp`` are computed and dropped).
 
-    Invariants (enforced by the planner):
-
-    * ``band_rows = (blocks_per_slab·bmp // owp)·pool·stride`` with
-      ``(blocks_per_slab·bmp) % owp == 0`` — every slab covers whole pooled
-      output rows, so pool windows never straddle a slab seam and the band
-      index map stays a pure division of the row-block grid index.
-    * ``halo_rows`` is the smallest **divisor** of ``band_rows`` that is
-      ≥ ``max(ky - stride, 0)`` (0 when no overlap is needed): divisibility
-      makes the halo offset ``(slab+1)·band_rows`` block-aligned for the
-      halo BlockSpec without constraining ``band_rows`` itself.
-    * ``rows_total = n_slabs·band_rows + halo_rows`` is the row count the
-      kernel operand must carry — the wrapper slices/zero-pads the padded
-      image to it (sliced rows are provably never gathered; padded rows are
-      only touched by clamped M-pad rows, which replay valid windows).
+    ``n_slabs == 1`` keeps the whole padded image resident.  Otherwise the
+    image is cut into row bands of ``rows_out`` pooled output rows
+    (``band_rows = rows_out·S`` padded-image rows), each carrying the
+    ``halo_rows = max(ky - stride, 0)`` rows the next band's windows
+    overlap; the kernel's block index moves to the next slab every
+    ``n_blocks`` pixel blocks, so Pallas's pipeline prefetches slab ``s+1``
+    while slab ``s`` computes.  ``bmp`` is the pooled wide pixels (lanes) of
+    one block, ``lanes`` the flattened length of one slab's phase images
+    (blocks plus the lane halo the largest tap offset reaches).
     """
 
     n_slabs: int
-    blocks_per_slab: int
+    rows_out: int
     band_rows: int
     halo_rows: int
-    rows_total: int
+    wq: int
+    bmp: int
+    n_blocks: int
+    lanes: int
 
-    @property
-    def fetched_rows(self) -> int:
-        """Image rows HBM streams per image: ``rows_total`` when the whole
-        image is resident, else each slab refetches its halo."""
-        if self.n_slabs == 1:
-            return self.rows_total
-        return self.n_slabs * (self.band_rows + self.halo_rows)
+    def image_elems(self, geom: ConvGeom) -> int:
+        """Elements of one image's kernel operand (every slab, halo and
+        alignment padding included) — what HBM streams per image."""
+        return self.n_slabs * geom.phase ** 2 * geom.c_in * self.lanes
 
 
-def _dequant_tile(idx_tile, cb_row, gather: str, dtype):
-    """(bk, bn) uint8 indices + (B,) codebook → (bk, bn) weights."""
-    B = cb_row.shape[0]
-    if gather == "take":
-        return cb_row[idx_tile.astype(jnp.int32)].astype(dtype)
-    # one-hot contraction: Σ_b cb[b]·[idx=b] — the PAS selection network in
-    # vectorized form; guaranteed-lowerable everywhere.
-    w = jnp.zeros(idx_tile.shape, dtype=jnp.float32)
-    for b in range(B):
-        w = jnp.where(idx_tile == b, cb_row[b], w)
+def _dequant_tile(idx_tile, cb_ref, g, dtype):
+    """(bk, bn) indices + SMEM ``(G, B)`` codebook row ``g`` → weights.
+
+    One-hot select ``Σ_b cb[g, b]·[idx = b]``: int32 compares (Mosaic
+    refuses the uint8 ones) and SMEM scalar reads — no vector gather."""
+    idx = idx_tile.astype(jnp.int32)
+    w = jnp.zeros(idx.shape, jnp.float32)
+    for b in range(cb_ref.shape[1]):
+        w = jnp.where(idx == b, cb_ref[g, b], w)
     return w.astype(dtype)
 
 
 def _unpack_int4_tile(packed):
-    """(bk//2, bn) packed → (bk, bn): row 2i = lo nibble, row 2i+1 = hi."""
-    lo = packed & 0x0F
-    hi = packed >> 4
-    out = jnp.stack([lo, hi], axis=1)  # (bk//2, 2, bn)
-    return out.reshape(packed.shape[0] * 2, packed.shape[1])
+    """(bk//2, bn) packed → (bk, bn) int32: row 2i = lo nibble, 2i+1 = hi.
+    Widened to int32 first — Mosaic cannot shift uint8 vectors."""
+    p = packed.astype(jnp.int32)
+    out = jnp.stack([p & 0x0F, p >> 4], axis=1)  # (bk//2, 2, bn)
+    return out.reshape(p.shape[0] * 2, p.shape[1])
 
 
-def patch_tile(img, m0, q0, *, geom: ConvGeom, bm: int, bk: int, gs: int,
-               gs_pad: int, row0=0):
-    """Assemble one ``(bm, bk)`` im2col tile from the VMEM-resident image.
+def _dot(lhs, w, transposed: bool):
+    """``lhs @ w`` at full f32 precision; ``transposed`` lhs is the implicit
+    kernels' ``(bk, M)`` tile (reduction on sublanes, pixels on lanes).
 
-    ``img`` is a single padded image (``(H, W, C)`` when ``geom.nhwc`` else
-    ``(C, H, W)``); rows are output pixels ``[m0, m0+bm)``, columns are
-    *padded* GEMM reduction positions ``[q0, q0+bk)``.  ``row0`` rebases the
-    image-row coordinate when ``img`` is a slab (band+halo) rather than the
-    whole image: the gather reads ``img[iy - row0]`` where ``row0`` is the
-    slab's first image row (0 for the whole-image schedule — the slab
-    planner guarantees every row a slab's output blocks touch lands in
-    ``[row0, row0 + band_rows + halo_rows)``).  Each padded position is
-    unmapped to its logical ``(c, ky, kx)`` patch element:
+    ``HIGHEST`` keeps f32 operands f32 on the MXU (Mosaic's default rounds
+    them to bfloat16), so the chip computes what the interpret-mode tests
+    check; bf16 operands take the default (Mosaic refuses an fp32 contract
+    precision on bf16 operands)."""
+    dims = (((0,) if transposed else (1,), (0,)), ((), ()))
+    precision = (jax.lax.Precision.HIGHEST if lhs.dtype == jnp.float32
+                 else None)
+    return jax.lax.dot_general(lhs, w, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
 
-      ``g = q // gs_pad`` picks the codebook group, ``r = q % gs_pad`` the
-      row within it; rows with ``r >= gs`` are the tile-plan K-pad and rows
-      with ``g·gs + r >= conv_k`` the §3 pack-time K-pad — both read **zero**
-      (the in-kernel analogue of the zero patch columns the explicit path
-      pads in), pairing with the reserved zero-codebook bin.  M-pad rows
-      clamp to the last pixel/window and are sliced off outside.
 
-    With ``geom.pool > 1`` rows are **window-major**: row ``m`` is within-
-    window offset ``s = m % pool²`` of pooled pixel ``pp = m // pool²``, so
-    each consecutive ``pool²`` rows form one pool window and the fused
-    epilogue can max-reduce them with a pure reshape.  M-pad rows clamp at
-    *window* granularity (``pp`` clamps, ``s`` keeps cycling), so a pad
-    window replays the last valid window — never a mix of valid and garbage
-    rows, which is what makes the pooled write-through safe without any
-    ``-inf`` row masking.  ``pool == 1`` degenerates to the row-major pixel
-    unmapping exactly (``pp = m``, ``s = 0``).
-    """
-    m = m0 + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
-    pw = geom.pool * geom.pool
-    pp = jnp.minimum(m // pw, geom.P_out - 1)
-    s = m % pw
-    oy = (pp // geom.owp) * geom.pool + s // geom.pool
-    ox = (pp % geom.owp) * geom.pool + s % geom.pool
-    q = q0 + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-    g, r = q // gs_pad, q % gs_pad
-    ql = g * gs + jnp.minimum(r, gs - 1)
-    valid = (r < gs) & (ql < geom.conv_k)
-    ql = jnp.minimum(ql, geom.conv_k - 1)
-    if geom.nhwc:  # channels-minor (ky, kx, c)
-        dy = ql // (geom.kx * geom.c_in)
-        dx = (ql // geom.c_in) % geom.kx
-        c = ql % geom.c_in
-    else:  # paper (c, ky, kx) loop order
-        c = ql // (geom.ky * geom.kx)
-        dy = (ql // geom.kx) % geom.ky
-        dx = ql % geom.kx
-    iy = oy * geom.stride + dy - row0  # (bm, bk) via broadcast
-    ix = ox * geom.stride + dx
-    c = jnp.broadcast_to(c, iy.shape)
-    vals = img[iy, ix, c] if geom.nhwc else img[c, iy, ix]
-    return jnp.where(valid, vals, jnp.zeros((), img.dtype))
+def epilogue_store(y, b_ref, o_ref, *, relu: bool, pool: int,
+                   transposed: bool):
+    """The fused write-through shared by every kernel here: bias, ReLU, then
+    the max-pool.  Explicit rows are window-major (each consecutive
+    ``pool²`` rows one window); the implicit kernels' rows are
+    window-offset-major (``pool²`` groups of one block's pixels each)."""
+    if b_ref is not None:
+        y = y + b_ref[...]  # (1, bn) broadcasts over rows
+    if relu:
+        y = jnp.maximum(y, 0.0)
+    if pool > 1 and transposed:
+        pw = pool * pool
+        n = y.shape[0] // pw
+        y = functools.reduce(
+            jnp.maximum, [y[w * n:(w + 1) * n] for w in range(pw)]
+        )
+    elif pool > 1:
+        y = max_pool_rows(y, pool)
+    o_ref[...] = y.reshape(o_ref.shape)
 
 
 def _fused_dequant_step(
-    x_tile, idx_ref, cb_ref, b_ref, o_ref, acc_ref=None, *, k, n_k: int,
-    packed: bool, gather: str, relu: bool, pool: int = 1,
+    lhs, idx_ref, cb_ref, b_ref, o_ref, acc_ref=None, *, k, g, n_k: int,
+    packed: bool, relu: bool, pool: int = 1, transposed: bool = False,
 ):
     """The shared per-k-step body of BOTH entry points: unpack+dequant the
-    idx tile, accumulate ``x_tile @ w``, and fuse the bias-add / ReLU
+    idx tile, accumulate ``lhs @ w``, and fuse the bias-add / ReLU / max-pool
     epilogue into the last-k-step write-through — so a conv layer with
-    bias+activation stays a single pallas_call.  ``o_ref`` may carry a
-    leading length-1 batch axis (the conv grid); the accumulate reshapes to
-    it and ``(1, bn)`` bias broadcasting covers both ranks.
-
-    ``pool > 1`` additionally max-reduces each group of ``pool²``
-    window-major rows in the write-through (after bias/ReLU, matching the
-    unfused conv→epilogue→``reduce_window`` order), so the stored block is
-    the pooled ``(bm/pool², bn)`` shape and the pre-pool activations never
-    leave VMEM.  The pre-pool accumulator then lives in the ``acc_ref``
-    VMEM scratch instead of ``o_ref`` (their shapes differ).
-    """
+    bias+activation(+pool) stays a single pallas_call.  ``o_ref`` may carry
+    leading length-1 axes (the conv grid).  With ``pool > 1`` the pre-pool
+    accumulator lives in the ``acc_ref`` VMEM scratch instead of ``o_ref``
+    (their shapes differ)."""
     idx_tile = idx_ref[...]
     if packed:
         idx_tile = _unpack_int4_tile(idx_tile)
-    w = _dequant_tile(idx_tile, cb_ref[0], gather, x_tile.dtype)
-    acc = jnp.dot(x_tile, w, preferred_element_type=jnp.float32)
-    if pool == 1:
-        o_ref[...] += acc.reshape(o_ref.shape)
+    w = _dequant_tile(idx_tile, cb_ref, g, lhs.dtype)
+    acc = _dot(lhs, w, transposed)
+    target = acc_ref if pool > 1 else o_ref
+    target[...] += acc.reshape(target.shape)
 
-        if b_ref is not None or relu:
+    if pool > 1 or b_ref is not None or relu:
 
-            @pl.when(k == n_k - 1)
-            def _finish():
-                y = o_ref[...]
-                if b_ref is not None:
-                    y = y + b_ref[...]  # (1, bn) broadcasts over rows
-                if relu:
-                    y = jnp.maximum(y, 0.0)
-                o_ref[...] = y
-
-        return
-    acc_ref[...] += acc
-
-    @pl.when(k == n_k - 1)
-    def _finish_pooled():
-        y = acc_ref[...]
-        if b_ref is not None:
-            y = y + b_ref[...]
-        if relu:
-            y = jnp.maximum(y, 0.0)
-        o_ref[...] = max_pool_rows(y, pool).reshape(o_ref.shape)
+        @pl.when(k == n_k - 1)
+        def _finish():
+            y = target[...].reshape(acc.shape)
+            epilogue_store(y, b_ref, o_ref, relu=relu, pool=pool,
+                           transposed=transposed)
 
 
 def _kernel(
-    x_ref, idx_ref, cb_ref, *rest, packed: bool, gather: str, n_k: int,
-    relu: bool, pool: int,
+    x_ref, idx_ref, cb_ref, *rest, packed: bool, n_k: int,
+    blocks_per_group: int, relu: bool, pool: int,
 ):
     if pool > 1:
         acc_ref, rest = rest[-1], rest[:-1]
@@ -294,15 +260,20 @@ def _kernel(
 
     @pl.when(k == 0)
     def _zero():
-        if pool > 1:
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-        else:
-            o_ref[...] = jnp.zeros_like(o_ref)
+        z = acc_ref if pool > 1 else o_ref
+        z[...] = jnp.zeros_like(z)
 
     _fused_dequant_step(
         x_ref[...], idx_ref, cb_ref, b_ref, o_ref, acc_ref,
-        k=k, n_k=n_k, packed=packed, gather=gather, relu=relu, pool=pool,
+        k=k, g=k // blocks_per_group, n_k=n_k, packed=packed, relu=relu,
+        pool=pool,
     )
+
+
+def _params(n_grid: int):
+    sem = ("parallel",) * (n_grid - 1) + ("arbitrary",)
+    return pltpu.CompilerParams(dimension_semantics=sem,
+                                vmem_limit_bytes=VMEM_LIMIT)
 
 
 def pasm_matmul_kernel_call(
@@ -316,7 +287,6 @@ def pasm_matmul_kernel_call(
     bm: int = 128,
     bn: int = 128,
     bk: int = 512,
-    gather: str = "take",
     relu: bool = False,
     pool: int = 1,
     interpret: bool = False,
@@ -335,7 +305,7 @@ def pasm_matmul_kernel_call(
     M, K = x.shape
     N = idx.shape[1]
     assert K == logical_k
-    G, B = codebook.shape
+    G, _ = codebook.shape
     group_size = K // G
     assert group_size % bk == 0, (group_size, bk)
     pw = pool * pool
@@ -344,14 +314,12 @@ def pasm_matmul_kernel_call(
 
     # index maps return BLOCK indices (scaled by block_shape internally)
     idx_block = (bk // 2, bn) if packed else (bk, bn)
-    blocks_per_group = group_size // bk
-
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
         pl.BlockSpec(idx_block, lambda i, j, k: (k, j)),
-        pl.BlockSpec((1, B), lambda i, j, k: (k // blocks_per_group, 0)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
-    operands = [x, idx, codebook]
+    operands = [x, idx, codebook.astype(jnp.float32)]
     if bias is not None:
         assert bias.shape == (1, N), bias.shape
         in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k: (0, j)))
@@ -359,105 +327,155 @@ def pasm_matmul_kernel_call(
 
     return pl.pallas_call(
         functools.partial(
-            _kernel, packed=packed, gather=gather, n_k=n_k, relu=relu, pool=pool
+            _kernel, packed=packed, n_k=n_k,
+            blocks_per_group=group_size // bk, relu=relu, pool=pool,
         ),
         grid=(M // bm, N // bn, n_k),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm // pw, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M // pw, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)] if pool > 1 else [],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=_params(3),
         interpret=interpret,
     )(*operands)
 
 
-def _slab_image(x_ref, halo_ref, geom: ConvGeom, slab):
-    """Kernel-side slab assembly shared by both implicit conv bodies.
+# ---------------------------------------------------------------------------
+# implicit-GEMM convolution: phase layout + in-kernel patch-tile assembly
+# ---------------------------------------------------------------------------
 
-    Whole-image schedule (``slab is None``): the block IS the padded image.
-    Slab schedule: concatenate the band block with its halo block (the first
-    ``halo_rows`` rows of the next band — same array, second operand) along
-    the image-row axis, and return the slab's first image row so
-    :func:`patch_tile` can rebase its gather coordinates.
+
+def phase_slabs(xp: jax.Array, geom: ConvGeom, plan: SlabPlan) -> jax.Array:
+    """Padded NCHW images ``(B, C, hp, wp)`` → the kernel's image operand
+    ``(B, n_slabs, S², C, lanes)`` (XLA relayout, outside the kernel).
+
+    Phase ``py·S + px`` of slab ``s`` is rows ``s·band_rows + py::S`` and
+    columns ``px::S``, flattened at pitch ``wq``.  Rows past the image are
+    zero; rows no valid output reads are cropped.  Slab halos are copied
+    into each slab (``n_slabs·(band+halo)`` rows, the same rows a row-band
+    pipeline would re-fetch)."""
+    S, wq, L = geom.phase, plan.wq, plan.lanes
+    hs = -(-L // wq)  # phase rows one slab's lanes span
+    rows = (plan.n_slabs - 1) * plan.band_rows + hs * S
+    B, C, hp, wp = xp.shape
+    xp = xp[:, :, :rows]
+    xp = jnp.pad(xp, ((0, 0), (0, 0), (0, rows - xp.shape[2]),
+                      (0, wq * S - wp)))
+    slabs = []
+    for s in range(plan.n_slabs):
+        xs = xp[:, :, s * plan.band_rows: s * plan.band_rows + hs * S]
+        xs = xs.reshape(B, C, hs, S, wq, S).transpose(0, 3, 5, 1, 2, 4)
+        slabs.append(xs.reshape(B, S * S, C, hs * wq)[..., :L])
+    return jnp.stack(slabs, axis=1)
+
+
+def assemble_tile(img_ref, t_ref, base, q0, *, geom: ConvGeom, plan: SlabPlan,
+                  bk: int, gs: int, gs_pad: int):
+    """Write the transposed ``(bk, pool²·bmp)`` patch tile into ``t_ref``.
+
+    ``img_ref`` is one slab's ``(1, 1, S², C, lanes)`` phase block; tile
+    row ``r`` is *padded* GEMM reduction position ``q = q0 + r``, lane group
+    ``w`` holds window offset ``w = (wy, wx)`` of the block's ``bmp`` pooled
+    wide pixels starting at lane ``base``.  Each padded position unmaps to
+    its logical ``(c, dy, dx)`` patch element in the layout's order:
+    ``g = q // gs_pad`` picks the codebook group and ``r = q % gs_pad`` the
+    row in it; rows with ``r >= gs`` (tile-plan K-pad) or ``g·gs + r >=
+    conv_k`` (the §3 pack-time K-pad) read **zero**, pairing with the
+    reserved zero-codebook bin exactly like the explicit path's zero patch
+    columns.  Tap ``(dy, dx)`` under window offset ``w`` reads phase
+    ``(ey % S, ex % S)`` at flat offset ``(ey // S)·wq + ex // S`` with
+    ``ey = wy·stride + dy`` — a unit-stride lane window, fetched as an
+    aligned load and a lane rotate (Mosaic has no unaligned dynamic lane
+    slice and no vector gather).
     """
-    img = x_ref[0]
-    if slab is None:
-        return img, 0
-    if halo_ref is not None:
-        img = jnp.concatenate([img, halo_ref[0]], axis=0 if geom.nhwc else 1)
-    row0 = (pl.program_id(1) // slab.blocks_per_slab) * slab.band_rows
-    return img, row0
+    S, pool, st = geom.phase, geom.pool, geom.stride
+    bmp, wq = plan.bmp, plan.wq
+    pw = pool * pool
+    win = bmp + LANE
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, bmp), 0)
+
+    def decode(q):
+        """Padded reduction position → logical ``(c, dy, dx)`` + validity."""
+        g, rr = q // gs_pad, q % gs_pad
+        ql = g * gs + jnp.minimum(rr, gs - 1)
+        valid = (rr < gs) & (ql < geom.conv_k)
+        ql = jnp.minimum(ql, geom.conv_k - 1)
+        if geom.nhwc:  # channels-minor (ky, kx, c)
+            return (ql % geom.c_in, ql // (geom.kx * geom.c_in),
+                    (ql // geom.c_in) % geom.kx, valid)
+        # paper (c, ky, kx) loop order
+        return (ql // (geom.ky * geom.kx), (ql // geom.kx) % geom.ky,
+                ql % geom.kx, valid)
+
+    def window(c, dy, dx, w):
+        """Channel ``c``'s lanes under tap ``(dy, dx)``, window offset ``w``."""
+        ey, ex = (w // pool) * st + dy, (w % pool) * st + dx
+        ph = (ey % S) * S + ex % S
+        off = (ey // S) * wq + ex // S
+        start = pl.multiple_of(base + (off // LANE) * LANE, LANE)
+        v = img_ref[0, 0, ph, pl.ds(c, 1), pl.ds(start, win)]
+        return pltpu.roll(v, (win - off % LANE) % win, 1)[:, :bmp]
+
+    def rows8(r8, carry):
+        # Mosaic stores only whole 8-row tiles at a dynamic row: gather 8
+        # tile rows into one (8, bmp) block per window offset, then store
+        r0 = pl.multiple_of(r8 * 8, 8)
+        blocks = [jnp.zeros((8, bmp), t_ref.dtype) for _ in range(pw)]
+        for i in range(8):
+            c, dy, dx, valid = decode(q0 + r0 + i)
+            keep = (sub == i) & valid
+            for w in range(pw):
+                v = jnp.broadcast_to(window(c, dy, dx, w), (8, bmp))
+                blocks[w] = jnp.where(keep, v, blocks[w])
+        for w in range(pw):
+            t_ref[pl.ds(r0, 8), w * bmp:(w + 1) * bmp] = blocks[w]
+        return carry
+
+    jax.lax.fori_loop(0, -(-bk // 8), rows8, 0)
 
 
-def _image_specs(x, geom: ConvGeom, slab):
-    """BlockSpecs (+ operands) for the implicit kernels' image input.
+def conv_image_spec(geom: ConvGeom, plan: SlabPlan, c_in: int):
+    """BlockSpec of the phase-layout image: one slab per block, advancing
+    every ``n_blocks`` pixel blocks (Pallas prefetches the next slab while
+    the current one computes and never refetches an unchanged block)."""
+    S, nb = geom.phase, plan.n_blocks
+    return pl.BlockSpec((1, 1, S * S, c_in, plan.lanes),
+                        lambda b, i, j, k: (b, i // nb, 0, 0, 0))
 
-    Whole-image: one ``(1, img...)`` block pinned at the origin.  Slabbed:
-    a ``band_rows`` row-band block whose index map advances every
-    ``blocks_per_slab`` row-blocks — Pallas's block pipeline prefetches the
-    next band while the current one computes and skips refetching unchanged
-    indices — plus, when ``halo_rows > 0``, the SAME array again as a
-    ``halo_rows``-row block at offset ``(slab+1)·band_rows`` (block-aligned
-    because ``halo_rows`` divides ``band_rows``).
-    """
-    if slab is None:
-        return [pl.BlockSpec((1,) + x.shape[1:],
-                             lambda b, i, j, k: (b, 0, 0, 0))], [x]
-    S, Hh, bps = slab.band_rows, slab.halo_rows, slab.blocks_per_slab
-    rows_ax = 1 if geom.nhwc else 2
-    assert x.shape[rows_ax] == slab.rows_total, (x.shape, slab)
-    if geom.nhwc:
-        band = (1, S, x.shape[2], x.shape[3])
-        bmap = lambda b, i, j, k: (b, i // bps, 0, 0)
-        halo = (1, Hh, x.shape[2], x.shape[3])
-        hmap = lambda b, i, j, k: (b, (i // bps + 1) * S // Hh, 0, 0)
-    else:
-        band = (1, x.shape[1], S, x.shape[3])
-        bmap = lambda b, i, j, k: (b, 0, i // bps, 0)
-        halo = (1, x.shape[1], Hh, x.shape[3])
-        hmap = lambda b, i, j, k: (b, 0, (i // bps + 1) * S // Hh, 0)
-    specs, ops = [pl.BlockSpec(band, bmap)], [x]
-    if Hh:
-        specs.append(pl.BlockSpec(halo, hmap))
-        ops.append(x)
-    return specs, ops
+
+def conv_out_spec(plan: SlabPlan, bn: int):
+    nb = plan.n_blocks
+    return pl.BlockSpec((1, 1, plan.bmp, bn),
+                        lambda b, i, j, k: (b, i // nb, i % nb, j))
 
 
 def _conv_kernel(
-    x_ref, *refs, geom: ConvGeom, packed: bool, gather: str,
-    n_k: int, relu: bool, bm: int, bk: int, gs: int, gs_pad: int, slab=None,
+    x_ref, idx_ref, cb_ref, *rest, geom: ConvGeom, plan: SlabPlan,
+    packed: bool, n_k: int, relu: bool, bk: int, gs: int, gs_pad: int,
 ):
-    """Implicit-GEMM body: gather the patch tile instead of reading an
-    explicit x block, then the same :func:`_fused_dequant_step`."""
-    if slab is not None and slab.halo_rows:
-        halo_ref, refs = refs[0], refs[1:]
-    else:
-        halo_ref = None
-    idx_ref, cb_ref, *rest = refs
-    if geom.pool > 1:
-        acc_ref, rest = rest[-1], rest[:-1]
+    """Implicit-GEMM body: assemble the transposed patch tile, then the same
+    :func:`_fused_dequant_step` as the explicit GEMM."""
+    pool = geom.pool
+    if pool > 1:
+        rest, acc_ref = rest[:-1], rest[-1]
     else:
         acc_ref = None
+    *rest, t_ref = rest
     b_ref, o_ref = rest if len(rest) == 2 else (None, rest[0])
     k = pl.program_id(3)
 
     @pl.when(k == 0)
     def _zero():
-        if geom.pool > 1:
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-        else:
-            o_ref[...] = jnp.zeros_like(o_ref)
+        z = acc_ref if pool > 1 else o_ref
+        z[...] = jnp.zeros_like(z)
 
-    img, row0 = _slab_image(x_ref, halo_ref, geom, slab)
-    patch = patch_tile(
-        img, pl.program_id(1) * bm, k * bk,
-        geom=geom, bm=bm, bk=bk, gs=gs, gs_pad=gs_pad, row0=row0,
-    )
+    base = (pl.program_id(1) % plan.n_blocks) * plan.bmp
+    assemble_tile(x_ref, t_ref, base, k * bk, geom=geom, plan=plan, bk=bk,
+                  gs=gs, gs_pad=gs_pad)
     _fused_dequant_step(
-        patch, idx_ref, cb_ref, b_ref, o_ref, acc_ref,
-        k=k, n_k=n_k, packed=packed, gather=gather, relu=relu, pool=geom.pool,
+        t_ref[:bk, :], idx_ref, cb_ref, b_ref, o_ref, acc_ref,
+        k=k, g=k // (gs_pad // bk), n_k=n_k, packed=packed, relu=relu,
+        pool=pool, transposed=True,
     )
 
 
@@ -468,78 +486,61 @@ def pasm_conv_kernel_call(
     bias: "jax.Array | None" = None,
     *,
     geom: ConvGeom,
+    plan: SlabPlan,
     packed: bool,
     gs: int,
     gs_pad: int,
-    bm: int = 128,
     bn: int = 128,
     bk: int = 512,
-    gather: str = "take",
     relu: bool = False,
-    slab: "SlabPlan | None" = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Implicit-GEMM conv pallas_call: the image IS the ``x`` operand.
+    """Implicit-GEMM conv pallas_call over the phase-layout image.
 
-    ``x (B, img...)`` spatially padded per ``geom`` · ``idx (Kp or Kp//2, Np)``
-    · ``codebook (G, B)`` → ``(B, Pp, Np) f32`` where ``Pp`` rounds
-    ``geom.P_out`` up to the per-block *output* rows (real rows sliced off by
-    the caller).  Default (``slab`` None or single-slab): one whole padded
-    image is the per-grid-step ``x`` block — resident in VMEM across the
-    entire ``(i, j, k)`` tile loop of its batch element, so HBM streams the
-    image once per reuse window instead of ``ky·kx/stride²``× as patch rows.
-    With a multi-slab :class:`SlabPlan` the image streams as double-buffered
-    row bands instead (x pre-sliced/padded to ``slab.rows_total`` rows by
-    ops.py), so images past the VMEM budget run implicit too — the k-tile
-    sequence is untouched, so slab output stays bit-exact.  With
-    ``geom.pool > 1`` the grid walks window-major pre-pool rows (``bm`` per
-    block) but stores only the pooled ``bm/pool²`` rows — the fused
-    conv/ReLU/max-pool stage (slabs cover whole pooled rows, so windows
-    never straddle a seam).  Preconditions (enforced by ops.py):
-    ``gs_pad % bk == 0``, ``Np % bn == 0``, ``bm % pool² == 0``, bias
+    ``x (B, n_slabs, S², C, lanes)`` (:func:`phase_slabs`) · ``idx (Kp or
+    Kp//2, Np)`` · ``codebook (G, B)`` → ``(B, n_slabs, n_blocks·bmp, Np)
+    f32``: pooled wide pixels per slab (the caller drops the wide columns
+    and pad rows).  Grid ``(B, n_slabs·n_blocks, Np/bn, Kp/bk)``; the
+    k-tile sequence is the explicit path's (same ``bk``/``gs_pad``), so the
+    dequantized weight tiles and their order match it.  Preconditions
+    (enforced by ops.py): ``gs_pad % bk == 0``, ``Np % bn == 0``, bias
     ``(1, Np)``.
     """
-    B_img = x.shape[0]
-    G, B = codebook.shape
+    B_img, n_slabs, _, c_in, _ = x.shape
     Np = idx.shape[1]
     Kp = idx.shape[0] * (2 if packed else 1)
+    G = codebook.shape[0]
     assert Kp == G * gs_pad, (Kp, G, gs_pad)
     assert gs_pad % bk == 0, (gs_pad, bk)
     pw = geom.pool * geom.pool
-    assert bm % pw == 0, (bm, geom.pool)
-    bmp = bm // pw  # stored (pooled) rows per block
     n_k = Kp // bk
-    Pp = (geom.P_out + bmp - 1) // bmp * bmp
-    blocks_per_group = gs_pad // bk
-    if slab is not None and slab.n_slabs == 1:
-        slab = None  # single slab ≡ the legacy whole-image schedule
 
     idx_block = (bk // 2, bn) if packed else (bk, bn)
-    img_specs, operands = _image_specs(x, geom, slab)
-    in_specs = img_specs + [
+    in_specs = [
+        conv_image_spec(geom, plan, c_in),
         pl.BlockSpec(idx_block, lambda b, i, j, k: (k, j)),
-        pl.BlockSpec((1, B), lambda b, i, j, k: (k // blocks_per_group, 0)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
-    operands = operands + [idx, codebook]
+    operands = [x, idx, codebook.astype(jnp.float32)]
     if bias is not None:
         assert bias.shape == (1, Np), bias.shape
         in_specs.append(pl.BlockSpec((1, bn), lambda b, i, j, k: (0, j)))
         operands.append(bias)
+    scratch = [pltpu.VMEM((-(-bk // 8) * 8, pw * plan.bmp), x.dtype)]
+    if geom.pool > 1:
+        scratch.append(pltpu.VMEM((pw * plan.bmp, bn), jnp.float32))
 
     return pl.pallas_call(
         functools.partial(
-            _conv_kernel, geom=geom, packed=packed, gather=gather, n_k=n_k,
-            relu=relu, bm=bm, bk=bk, gs=gs, gs_pad=gs_pad, slab=slab,
+            _conv_kernel, geom=geom, plan=plan, packed=packed, n_k=n_k,
+            relu=relu, bk=bk, gs=gs, gs_pad=gs_pad,
         ),
-        grid=(B_img, Pp // bmp, Np // bn, n_k),
+        grid=(B_img, n_slabs * plan.n_blocks, Np // bn, n_k),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bmp, bn), lambda b, i, j, k: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((B_img, Pp, Np), jnp.float32),
-        scratch_shapes=(
-            [pltpu.VMEM((bm, bn), jnp.float32)] if geom.pool > 1 else []
-        ),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-        ),
+        out_specs=conv_out_spec(plan, bn),
+        out_shape=jax.ShapeDtypeStruct(
+            (B_img, n_slabs, plan.n_blocks * plan.bmp, Np), jnp.float32),
+        scratch_shapes=scratch,
+        compiler_params=_params(4),
         interpret=interpret,
     )(*operands)

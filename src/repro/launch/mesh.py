@@ -55,9 +55,15 @@ def make_conv_mesh(shape=None):
             f"mesh shape {shape} needs {n} devices but only "
             f"{len(jax.devices())} are visible"
         )
-    # no axis_types: explicit-sharding AxisType postdates this jax; the conv
-    # dispatch only uses the mesh through shard_map, which doesn't need it
-    return jax.make_mesh(shape, ("data", "model"), devices=jax.devices()[:n])
+    # Auto axes: the conv dispatch shards through shard_map and leaves the
+    # glue (batch pad/slice, head) to the partitioner — jax.make_mesh would
+    # otherwise default to Explicit axes, whose sharding-in-types refuses
+    # that glue's indexing
+    return jax.make_mesh(
+        shape, ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=jax.devices()[:n],
+    )
 
 
 def axis_sizes(mesh) -> dict:

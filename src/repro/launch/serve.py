@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_cnn_config, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api, cnn
 from repro.models.common import quantize_params, weight_bytes
 from repro.serve.batcher import CnnBatcher, MixedBatcher
@@ -90,6 +91,7 @@ def main(argv=None):
     ap.add_argument("--faults-seed", type=int, default=None,
                     help="chaos drill: inject a FaultPlan sampled from this seed")
     args = ap.parse_args(argv)
+    print(f"[serve] compile cache: {enable_compile_cache()}")
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = api.get_model(cfg)

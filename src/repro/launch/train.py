@@ -44,6 +44,7 @@ from repro import ft
 from repro.ckpt import checkpoint as ckpt
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, synthetic_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.models.common import ShardCtx, quantize_params
 from repro.train import faults as train_faults
@@ -85,6 +86,7 @@ def main(argv: Optional[list] = None) -> int:
                     help="chaos drill: sample a TrainFaultPlan from this seed")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    print(f"[train] compile cache: {enable_compile_cache()}")
 
     cfg = get_config(args.arch, smoke=args.smoke)
     ocfg = opt.AdamWConfig(lr=args.lr, total_steps=args.steps, warmup_steps=max(args.steps // 20, 5))

@@ -50,12 +50,13 @@ from repro.core import qat as _qat
 from repro.models.common import Initializer
 
 __all__ = ["stages", "feature_shape", "init_params", "quantize", "forward",
-           "forward_dense", "conv_mesh", "qat_codebooks", "qat_apply",
+           "forward_dense", "conv_mesh", "place", "qat_codebooks", "qat_apply",
            "qat_forward", "qat_requantize"]
 
 #  CNNConfig.impl == conv2d engine (kernel_implicit = implicit-GEMM Pallas;
 #  auto lets conv2d pick per layer under cfg.vmem_budget)
-_IMPLS = ("auto", "einsum", "kernel", "kernel_implicit", "pas_kernel")
+_IMPLS = ("auto", "einsum", "kernel", "kernel_implicit", "pas_kernel",
+          "pas_kernel_implicit")
 
 
 def stages(cfg: CNNConfig) -> list:
@@ -103,7 +104,7 @@ def conv_mesh(cfg: CNNConfig):
     return make_conv_mesh(cfg.mesh_shape)
 
 
-def _place(params: dict, mesh) -> dict:
+def place(params: dict, mesh) -> dict:
     """Put every leaf on ``mesh`` per the models/sharding.py CNN rules."""
     from repro.launch.mesh import axis_sizes
     from repro.models import sharding as _sharding
@@ -136,7 +137,7 @@ def quantize(params: dict, cfg: CNNConfig, *, iters: int = 16, mesh=None) -> dic
             q = q.pack(layout=cfg.layout)
         convs.append(q)
     out = {"conv": convs, "head": params["head"]}
-    return _place(out, mesh) if mesh is not None else out
+    return place(out, mesh) if mesh is not None else out
 
 
 # NOTE: the former ``_max_pool`` helper is gone — conv stages pass ``pool=``
@@ -157,7 +158,6 @@ def _head(x: jax.Array, head: dict, mesh=None) -> jax.Array:
     xf = x.reshape(B, -1)
     if mesh is None:
         return xf @ head["w"] + head["b"]
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import data_model_sizes, n_shard_axis
@@ -168,10 +168,10 @@ def _head(x: jax.Array, head: dict, mesh=None) -> jax.Array:
     if pad:
         xf = jnp.pad(xf, ((0, pad), (0, 0)))
     ns = n_shard_axis(mesh, head["w"].shape[1])
-    y = shard_map(
+    y = jax.shard_map(
         lambda xl, wl, bl: xl @ wl + bl,
         mesh=mesh, in_specs=(P("data", None), P(None, ns), P(ns)),
-        out_specs=P("data", ns), check_rep=False,
+        out_specs=P("data", ns), check_vma=False,
     )(xf, head["w"], head["b"])
     return y[:B]
 
@@ -190,8 +190,9 @@ def forward(
     the fused-dequant ``pasm_matmul`` over an explicit im2col patch matrix,
     ``kernel_implicit`` the implicit-GEMM ``pasm_conv2d`` (patch tiles
     assembled in VMEM, no patch matrix in HBM), ``pas_kernel`` the
-    paper-faithful two-phase ``pas_matmul`` (all with the bias/ReLU epilogue
-    fused into the pallas_call), ``einsum`` the pure-XLA reference port.
+    paper-faithful two-phase ``pas_matmul`` and ``pas_kernel_implicit`` its
+    implicit-GEMM ``pas_conv2d`` (all with the bias/ReLU epilogue fused into
+    the pallas_call), ``einsum`` the pure-XLA reference port.
 
     Each stage's max-pool rides ``conv2d(pool=)``: on the Pallas engines the
     pool fuses into the conv kernel's epilogue (one ``pallas_call`` per
@@ -309,4 +310,4 @@ def qat_requantize(
             q = q.pack(layout=cfg.layout)
         convs.append(q)
     out = {"conv": convs, "head": params["head"]}
-    return _place(out, mesh) if mesh is not None else out
+    return place(out, mesh) if mesh is not None else out

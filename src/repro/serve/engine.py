@@ -32,9 +32,12 @@ Fault tolerance (DESIGN.md §2.4) — every leg flows through ``step()``:
   never leaks to the next occupant.
 - **Retry + degradation**: retryable failures (numeric, injected transient
   errors) re-enter the queue up to ``max_retries`` with capped exponential
-  tick-based backoff; a persistent kernel failure at a jit boundary flips
-  that closure's dispatch from the Pallas ``kernel`` path to the
-  ``dequant`` oracle once, memoized — degraded but serving.
+  tick-based backoff; an injected persistent kernel fault (the FaultPlan
+  drill) flips that closure's dispatch from the Pallas ``kernel`` path to
+  the ``dequant`` oracle once, memoized — degraded but serving.  A real
+  exception at a jit boundary (a Mosaic lowering or VMEM error, a bad
+  shape) raises: serving on the oracle would hide that the device path is
+  broken.
 - **Fault hooks**: a seeded :class:`~repro.serve.faults.FaultPlan` injects
   NaN/raise/slow faults at the engine's phase boundaries, fully
   deterministic (tick/slot/uid keyed — no wall clock).
@@ -234,36 +237,31 @@ class Engine:
         return self._prefill_by_bucket[key]
 
     def _call(self, key: str, build: Callable, *args):
-        """Run a jitted closure with one-shot kernel→dequant degradation.
+        """Run a jitted closure with the one-shot kernel→dequant drill.
 
-        A persistent failure at the jit boundary (``pallas_call``
-        lowering/VMEM errors — or an injected FaultPlan ``kernel`` fault)
-        flips THIS closure's dispatch to the dequant oracle path, memoized,
-        and replays the call: degraded but serving.  :class:`FaultInjected`
-        (transient, handled per-request or per-tick) passes through.
+        An injected FaultPlan ``kernel`` fault flips THIS closure's dispatch
+        to the dequant oracle path, memoized, and replays the call: degraded
+        but serving.  Real exceptions raise (a lowering error must not pass
+        as a degraded success), and :class:`FaultInjected` (transient,
+        handled per-request or per-tick) passes through.
         """
-        degraded = key in self._degraded
-        cfg = self._degraded_cfg if degraded else self.cfg
-        try:
-            if (not degraded and self.faults is not None
-                    and self.faults.kernel_broken(key)):
-                raise RuntimeError(f"injected persistent kernel failure: {key}")
-            return build(cfg)(*args)
-        except FaultInjected:
-            raise
-        except Exception as e:  # noqa: BLE001 — degradation boundary
-            if degraded or self._degraded_cfg is None:
-                raise
-            self._degraded.add(key)
-            self.metrics.incr("n_degraded")
-            warnings.warn(
-                f"engine: closure {key!r} failed on the "
-                f"{self.cfg.quant.impl!r} path ({type(e).__name__}: {e}); "
-                f"degrading its dispatch to impl='dequant'",
-                RuntimeWarning,
-                stacklevel=2,
-            )
+        if key in self._degraded:
             return build(self._degraded_cfg)(*args)
+        if self.faults is None or not self.faults.kernel_broken(key):
+            return build(self.cfg)(*args)
+        err = f"injected persistent kernel failure: {key}"
+        if self._degraded_cfg is None:
+            raise RuntimeError(err)
+        self._degraded.add(key)
+        self.metrics.incr("n_degraded")
+        warnings.warn(
+            f"engine: closure {key!r} failed on the "
+            f"{self.cfg.quant.impl!r} path ({err}); "
+            f"degrading its dispatch to impl='dequant'",
+            RuntimeWarning,
+            stacklevel=2,
+        )
+        return build(self._degraded_cfg)(*args)
 
     # -- request lifecycle ---------------------------------------------------
 

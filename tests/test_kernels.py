@@ -44,10 +44,13 @@ def test_pasm_matmul_vs_oracle(M, K, N, bins, groups, dtype):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("gather", ["take", "onehot"])
-def test_gather_strategies_agree(gather):
-    x, t = _mk(8, 64, 32, 8, 1, jnp.float32)
-    got = ops.pasm_matmul(x, t, gather=gather, interpret=True)
+@pytest.mark.parametrize("bins", [8, 64], ids=["int4", "uint8"])
+def test_gather_strategies_agree(bins):
+    """The kernel's one-hot select (the only dequant Mosaic lowers) agrees
+    with the oracle's codebook gather, packed int4 and plain uint8 indices."""
+    x, t = _mk(8, 64, 32, bins, 1, jnp.float32)
+    assert t.packed == (bins <= 16)
+    got = ops.pasm_matmul(x, t, interpret=True)
     want = ref.pasm_matmul_ref(x, t.idx, t.codebook, packed=t.packed)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 

@@ -76,6 +76,27 @@ def test_quantize_like_reassigns():
     assert err <= base + 1e-6
 
 
+@pytest.mark.parametrize("n,bins,kind", [
+    (1, 16, "normal"), (7, 16, "normal"), (1000, 4, "normal"),
+    (35_000, 16, "ties"), (4096, 256, "zeros"), (12_288, 16, "wide"),
+])
+def test_sort_free_quantiles_match_jnp(n, bins, kind):
+    """k-means' quantile init selects order statistics without a sort and
+    returns jnp.quantile's values (ties, signed zeros, huge magnitudes)."""
+    rng = np.random.default_rng(n)
+    v = rng.standard_normal(n).astype(np.float32) * 0.05
+    if kind == "ties":
+        v = np.round(v * 20) / 20
+    elif kind == "zeros":
+        v[: n // 3], v[n // 3: n // 2] = 0.0, -0.0
+    elif kind == "wide":
+        v = v * np.float32(10.0) ** rng.integers(-30, 30, n).astype(np.float32)
+    qs = (jnp.arange(bins, dtype=jnp.float32) + 0.5) / bins  # as k-means
+    got = jax.jit(lambda v: pasm._quantiles(v, qs))(v)
+    want = jax.jit(lambda v: jnp.quantile(v, qs))(v)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_kmeans_deterministic():
     w = jax.random.normal(jax.random.PRNGKey(7), (64, 64))
     a = pasm.quantize(w, bins=16)

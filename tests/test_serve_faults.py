@@ -429,3 +429,32 @@ def test_degradation_unavailable_reraises():
     eng.submit(rng.integers(0, cfg.vocab, size=4), max_new=4)
     with pytest.raises(RuntimeError, match="injected persistent kernel"):
         eng.run_until_drained()
+
+
+@pytest.mark.parametrize("closure", ["decode", "prefill"])
+def test_real_kernel_error_raises(closure):
+    """A real exception at the jit boundary (here a stand-in for a Mosaic
+    lowering error) raises out of the engine instead of passing as a
+    degraded success: only the injected FaultPlan drill degrades."""
+    cfg, params = _setup("stablelm-3b")
+    qcfg = cfg.with_quant(enabled=True, bins=16, impl="kernel",
+                          min_weight_elems=1024)
+    qparams = quantize_params(params, qcfg)
+    eng = Engine(qcfg, qparams, batch_slots=1, max_seq=48)
+    name = f"_{closure}_fn"
+    healthy = getattr(eng, name)
+
+    def broken(*a):
+        if a[-1].quant.impl == "kernel":
+            def fail(*_):
+                raise RuntimeError("Mosaic failed to compile TPU kernel")
+            return fail
+        return healthy(*a)
+
+    setattr(eng, name, broken)
+    rng = np.random.default_rng(79)
+    eng.submit(rng.integers(0, cfg.vocab, size=4), max_new=3)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        eng.run_until_drained()
+    assert eng._degraded == set()
+    assert eng.metrics.rollup()["n_degraded"] == 0
