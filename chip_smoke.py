@@ -13,11 +13,10 @@ kernels compiled for the chip (``interpret=False``).  Every served class and
 logit is checked against the pure-XLA einsum port on the same quantized
 params at ``highest`` matmul precision.
 
-``--chips 4``: only the sharded stack — params placed per the CNN sharding
-rules (``cnn.place``, what ``cnn.quantize(mesh=)`` returns) +
+``--chips 4``: only the sharded stack — ``cnn.quantize(mesh=)`` +
 ``cnn.forward(mesh=)`` on a ``(2, 2)`` ``("data", "model")`` mesh — against
-its one-device result (bitwise per DESIGN.md §4.1; the max difference is
-reported if not).
+the same params on one device (bitwise per DESIGN.md §4.1; the max
+difference is reported if not).
 
 Exits non-zero, printing no result, when JAX finds no TPU or any phase
 fails.  The last line of a passing run is one JSON object naming the device.
@@ -34,14 +33,19 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
 
-# Normalized logit error bound, max|kernel − oracle| / max|oracle|.  The
-# kernels' f32 matmuls run at HIGHEST precision, but the served classifier
-# head is an XLA f32 matmul at default precision, which the v5e's MXU runs on
-# bfloat16-rounded operands (8-bit mantissa, 2^-9 relative each); summed
-# over the head's reduction that is up to about 1e-2 of the logit scale,
-# while a wrong tap, bin or pool window is an O(1) error.  5e-2 separates
-# the two.
-REL_BOUND = 5e-2
+# Normalized logit error bound, max|served − oracle| / max|oracle|.  Every
+# f32 matmul on the served path (conv kernels and classifier head) runs at
+# HIGHEST precision, as the oracle does, so the two differ by f32 summation
+# order only: 3.216e-7 (auto) and 2.291e-6 (PAS) on a TPU v5e.  One
+# bfloat16 pass anywhere reads far higher there: kernels and head at the
+# default precision 2.421e-2 (auto) and 5.024e-3 (PAS), the head alone at
+# the default 2.668e-3, the einsum port at the default 2.353e-2; a wrong
+# tap, bin or pool window is an O(1) error.  Each run also checks that the
+# einsum port at the default precision lands above the bound, so the bound
+# can still tell a single bfloat16 pass from HIGHEST on the chip it runs on.
+REL_BOUND = 1e-3
+N_IMAGES = 16  # mixed sizes ≤ 224: two image-size buckets per impl
+MAX_BATCH = 8
 
 
 def log(msg: str) -> None:
@@ -52,8 +56,6 @@ def parse(argv):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
-    ap.add_argument("--images", type=int, default=16)
-    ap.add_argument("--max-batch", type=int, default=8)
     return ap.parse_args(argv)
 
 
@@ -88,21 +90,27 @@ def stage_plans(cfg, params):
     return rows
 
 
-def oracle_logits(qparams, cfg, batches):
-    """The einsum port on the same quantized params, f32 at highest
-    precision, one jitted closure over every (8, C, 224, 224) batch."""
+def oracle_logits(qparams, cfg, batches, precision="highest"):
+    """The einsum port on the same quantized params, f32 matmuls at
+    ``precision``, one jitted closure over every (8, C, 224, 224) batch."""
     import jax
     import numpy as np
 
     from repro.models import cnn
 
     ref_cfg = dataclasses.replace(cfg, impl="einsum")
-    f = jax.jit(lambda p, x: cnn.forward(p, x, ref_cfg))
-    with jax.default_matmul_precision("highest"):
+    with jax.default_matmul_precision(precision):
+        f = jax.jit(lambda p, x: cnn.forward(p, x, ref_cfg))
         return [np.asarray(f(qparams, x)) for x in batches]
 
 
-def serve_phase(cfg, qparams, images, impl: str, max_batch: int, oracle):
+def normalized_error(got, want) -> float:
+    import numpy as np
+
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def serve_phase(cfg, qparams, images, impl: str, oracle):
     """Serve ``images`` through CnnBatcher under ``impl``; check vs oracle."""
     import jax
     import jax.numpy as jnp
@@ -115,12 +123,12 @@ def serve_phase(cfg, qparams, images, impl: str, max_batch: int, oracle):
     for row in stage_plans(cfg, qparams):
         log(f"{impl}: {row}")
     metrics = Metrics()
-    b = CnnBatcher(cfg, qparams, max_batch=max_batch, metrics=metrics,
+    b = CnnBatcher(cfg, qparams, max_batch=MAX_BATCH, metrics=metrics,
                    interpret=False)
     C = cfg.in_chw[0]
     for bucket in sorted({b._bucket_for(*im.shape[1:]) for im in images}):
         fn = b._classify_fn(bucket)
-        zeros = jnp.zeros((max_batch, C, *bucket), jnp.float32)
+        zeros = jnp.zeros((MAX_BATCH, C, *bucket), jnp.float32)
         t0 = time.perf_counter()
         np.asarray(jnp.argmax(fn(qparams, zeros), axis=-1))  # as flush() does
         t1 = time.perf_counter()
@@ -140,7 +148,7 @@ def serve_phase(cfg, qparams, images, impl: str, max_batch: int, oracle):
         f"latency s: " + " ".join(f"{v:.4f}" for v in lat))
 
     # kernel logits for the same padded batches, from the served closure
-    padded = _native_batches(images, cfg, max_batch)
+    padded = _native_batches(images, cfg)
     kern = []
     for x in padded:
         kern.append(np.asarray(b._classify_fn(tuple(b.native_hw))(
@@ -148,7 +156,7 @@ def serve_phase(cfg, qparams, images, impl: str, max_batch: int, oracle):
     kern = np.concatenate(kern)[: len(images)]
     ref = np.concatenate(oracle)[: len(images)]
     err = float(np.abs(kern - ref).max())
-    rel = err / float(np.abs(ref).max())
+    rel = normalized_error(kern, ref)
     top2 = np.sort(ref, axis=-1)[:, -2:]
     decidable = (top2[:, 1] - top2[:, 0]) > 2 * err
     want = ref.argmax(-1)
@@ -174,16 +182,16 @@ def serve_phase(cfg, qparams, images, impl: str, max_batch: int, oracle):
     return problems
 
 
-def _native_batches(images, cfg, max_batch):
-    """Images zero-padded to the native size, in ``max_batch`` chunks — the
+def _native_batches(images, cfg):
+    """Images zero-padded to the native size, in ``MAX_BATCH`` chunks — the
     same padding the batcher's classify closure applies."""
     import numpy as np
 
     C, H, W = cfg.in_chw
     out = []
-    for i in range(0, len(images), max_batch):
-        x = np.zeros((max_batch, C, H, W), np.float32)
-        for j, im in enumerate(images[i:i + max_batch]):
+    for i in range(0, len(images), MAX_BATCH):
+        x = np.zeros((MAX_BATCH, C, H, W), np.float32)
+        for j, im in enumerate(images[i:i + MAX_BATCH]):
             x[j, :, : im.shape[1], : im.shape[2]] = im
         out.append(x)
     return out
@@ -203,18 +211,26 @@ def one_chip(args, cfg, params):
         f"(packed={cfg.packed}, bins={cfg.bins}) in "
         f"{time.perf_counter() - t0:.2f}s")
     rng = np.random.default_rng(args.seed)
-    images = make_images(rng, args.images, cfg.in_chw[0], cfg.in_chw[1])
+    images = make_images(rng, N_IMAGES, cfg.in_chw[0], cfg.in_chw[1])
     log("image sizes: " + " ".join(f"{im.shape[1]}x{im.shape[2]}"
                                    for im in images))
+    batches = _native_batches(images, cfg)
     t0 = time.perf_counter()
-    oracle = oracle_logits(qparams, cfg,
-                           _native_batches(images, cfg, args.max_batch))
+    oracle = oracle_logits(qparams, cfg, batches)
     log(f"einsum oracle (highest precision) in {time.perf_counter() - t0:.2f}s")
     problems = []
+    bf16 = normalized_error(
+        np.concatenate(oracle_logits(qparams, cfg, batches, "default")),
+        np.concatenate(oracle))
+    log(f"einsum port at the default precision: normalized error {bf16:.3e} "
+        f"(bound {REL_BOUND:g})")
+    if bf16 <= REL_BOUND:
+        problems.append(f"default-precision control {bf16:.3e} is within the "
+                        f"bound {REL_BOUND:g}: the check cannot see bf16 passes")
     for impl in ("auto", "pas_kernel_implicit"):
         try:
             problems += [f"{impl}: {p}" for p in serve_phase(
-                cfg, qparams, images, impl, args.max_batch, oracle)]
+                cfg, qparams, images, impl, oracle)]
         except Exception as e:  # noqa: BLE001 — every phase must report
             traceback.print_exc()
             problems.append(f"{impl}: raised {type(e).__name__}: {e}")
@@ -231,12 +247,14 @@ def four_chips(args, cfg, params):
     mesh = make_conv_mesh((2, 2))
     log(f"mesh {dict(mesh.shape)} over {mesh.devices.size} devices")
     x = np.random.default_rng(args.seed).standard_normal(
-        (args.max_batch, *cfg.in_chw)).astype(np.float32)
+        (MAX_BATCH, *cfg.in_chw)).astype(np.float32)
     one = jax.jit(lambda p, x: cnn.forward(p, x, cfg, interpret=False))
     shard = jax.jit(lambda p, x: cnn.forward(p, x, cfg, interpret=False,
                                              mesh=mesh))
-    q1 = jax.jit(lambda p: cnn.quantize(p, cfg))(params)
-    qm = cnn.place(q1, mesh)  # cnn.quantize(mesh=)'s placement, same values
+    t0 = time.perf_counter()
+    qm = jax.block_until_ready(cnn.quantize(params, cfg, mesh=mesh))
+    log(f"quantized on the mesh in {time.perf_counter() - t0:.2f}s")
+    q1 = jax.device_put(qm, jax.devices()[0])  # the same values, one device
     for p, (conv, pool) in zip(qm["conv"], cnn.stages(cfg)):
         log(f"stage {conv.c_in}->{conv.c_out}: idx sharding "
             f"{p.idx.sharding.spec}")
@@ -249,7 +267,7 @@ def four_chips(args, cfg, params):
         f"(first calls, compile included)")
     bitwise = bool(np.array_equal(y1, ym))
     err = float(np.abs(y1 - ym).max())
-    rel = err / float(np.abs(y1).max())
+    rel = normalized_error(ym, y1)
     log(f"sharded vs one-device: bitwise={bitwise} max|Δlogit| {err:.3e} "
         f"normalized {rel:.3e}; top-1 agreement "
         f"{int((y1.argmax(-1) == ym.argmax(-1)).sum())}/{len(x)}")

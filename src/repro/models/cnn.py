@@ -50,7 +50,7 @@ from repro.core import qat as _qat
 from repro.models.common import Initializer
 
 __all__ = ["stages", "feature_shape", "init_params", "quantize", "forward",
-           "forward_dense", "conv_mesh", "place", "qat_codebooks", "qat_apply",
+           "forward_dense", "conv_mesh", "qat_codebooks", "qat_apply",
            "qat_forward", "qat_requantize"]
 
 #  CNNConfig.impl == conv2d engine (kernel_implicit = implicit-GEMM Pallas;
@@ -104,7 +104,7 @@ def conv_mesh(cfg: CNNConfig):
     return make_conv_mesh(cfg.mesh_shape)
 
 
-def place(params: dict, mesh) -> dict:
+def _place(params: dict, mesh) -> dict:
     """Put every leaf on ``mesh`` per the models/sharding.py CNN rules."""
     from repro.launch.mesh import axis_sizes
     from repro.models import sharding as _sharding
@@ -137,7 +137,7 @@ def quantize(params: dict, cfg: CNNConfig, *, iters: int = 16, mesh=None) -> dic
             q = q.pack(layout=cfg.layout)
         convs.append(q)
     out = {"conv": convs, "head": params["head"]}
-    return place(out, mesh) if mesh is not None else out
+    return _place(out, mesh) if mesh is not None else out
 
 
 # NOTE: the former ``_max_pool`` helper is gone — conv stages pass ``pool=``
@@ -153,11 +153,18 @@ def _head(x: jax.Array, head: dict, mesh=None) -> jax.Array:
     over ``data``, classes over ``model`` when divisible) so the contraction
     keeps the full feature axis per shard — XLA would otherwise split the
     model-sharded channel dim into a psum whose reduction order differs from
-    single-device, costing stack-level bit-exactness."""
+    single-device, costing stack-level bit-exactness.
+
+    The matmul runs at ``HIGHEST`` precision, as the conv kernels do: the
+    TPU's default rounds f32 operands to bfloat16."""
     B = x.shape[0]
     xf = x.reshape(B, -1)
+
+    def fc(xl, wl, bl):
+        return jnp.dot(xl, wl, precision=jax.lax.Precision.HIGHEST) + bl
+
     if mesh is None:
-        return xf @ head["w"] + head["b"]
+        return fc(xf, head["w"], head["b"])
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import data_model_sizes, n_shard_axis
@@ -169,8 +176,7 @@ def _head(x: jax.Array, head: dict, mesh=None) -> jax.Array:
         xf = jnp.pad(xf, ((0, pad), (0, 0)))
     ns = n_shard_axis(mesh, head["w"].shape[1])
     y = jax.shard_map(
-        lambda xl, wl, bl: xl @ wl + bl,
-        mesh=mesh, in_specs=(P("data", None), P(None, ns), P(ns)),
+        fc, mesh=mesh, in_specs=(P("data", None), P(None, ns), P(ns)),
         out_specs=P("data", ns), check_vma=False,
     )(xf, head["w"], head["b"])
     return y[:B]
@@ -310,4 +316,4 @@ def qat_requantize(
             q = q.pack(layout=cfg.layout)
         convs.append(q)
     out = {"conv": convs, "head": params["head"]}
-    return place(out, mesh) if mesh is not None else out
+    return _place(out, mesh) if mesh is not None else out

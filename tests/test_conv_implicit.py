@@ -5,7 +5,8 @@ Covers the perf_opt acceptance criteria:
 * ``engine="kernel_implicit"`` / ``"pas_kernel_implicit"`` are **bit-exact**
   against the explicit-im2col kernel paths for shared / packed / grouped
   params — same tile plan, same accumulation order — across paddings,
-  layouts and strides.
+  layouts and strides, wherever the k-tiles are shallow (DESIGN.md §3.1:
+  512-row k-tiles match to a few ulp).
 * jaxpr inspection: between the input and the single ``pallas_call`` there is
   no XLA ``gather``, no ``conv_general_dilated``, and no reshape producing
   the ``(B·P, K)`` patch matrix (the explicit path HAS one — the assertion
@@ -403,9 +404,12 @@ def test_slab_streams_image_failing_default_fits():
     assert (plan.band_rows, plan.halo_rows) == (88, 3)
     got = cv.conv2d(imgs, shared, conv, engine="auto", interpret=True)
     want = cv.conv2d(imgs, shared, conv, engine="kernel", interpret=True)
-    # K = 1936: the implicit kernel contracts its transposed (K, pixels)
-    # tile, which XLA:CPU reassociates differently from the explicit
-    # (pixels, K) tile at this depth — f32 rounding of a 1936-term sum
+    # K = 1936 runs in 512-deep k-tiles.  The implicit kernel contracts its
+    # (bk, pixels) tile over the leading axis, the explicit one its
+    # (pixels, bk) tile over the trailing axis, and XLA:CPU sums a k-tile
+    # this deep in another order for the two layouts (36-deep k-tiles, as
+    # in the bitwise tests above, sum alike).  Measured gap: 2.6e-6 at
+    # outputs up to 3.94, about 11 ulp; the tolerance is 1e-5
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     t = shared.gemm_tensor("NCHW")
