@@ -118,9 +118,10 @@ def measured_rows(rollup: dict, *, slots: int, tag: str) -> None:
             lat = rollup[f"{kind}_{pct}_latency_s"]
             record(f"serve.{tag}.{kind}.{pct}_latency", float(lat * 1e6),
                    derived=f"n={rollup[f'{kind}_n']}", n_requests=rollup[f"{kind}_n"])
-            ttft = rollup[f"{kind}_{pct}_ttft_s"]
-            record(f"serve.{tag}.{kind}.{pct}_ttft", float(ttft * 1e6),
-                   n_requests=rollup[f"{kind}_n"])
+            if kind == "lm":  # a classifier has no first token
+                ttft = rollup[f"lm_{pct}_ttft_s"]
+                record(f"serve.{tag}.lm.{pct}_ttft", float(ttft * 1e6),
+                       n_requests=rollup["lm_n"])
     tok_s = rollup["tok_s"]
     record(f"serve.{tag}.lm.tok_s", float(1e6 / tok_s) if tok_s else float("nan"),
            derived=f"{tok_s:.1f} tok/s", tok_s=tok_s)

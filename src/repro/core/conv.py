@@ -747,6 +747,7 @@ def conv2d(
     vmem_budget: Optional[int] = None,
     pool: int = 1,
     pool_impl: str = "auto",
+    name: Optional[str] = None,
 ) -> jax.Array:
     """The unified conv entry point: any params kind, any engine, any layout.
 
@@ -779,6 +780,12 @@ def conv2d(
     bit-exact vs the whole-image schedule — so the budget tunes slab
     sizing per target core rather than flipping ``auto`` to the explicit
     engine.
+
+    ``name=`` names the layer's kernel in the compiled program and in a
+    profiler trace: ``<name>_pasm``/``<name>_pas`` on the implicit engines,
+    ``<name>_pasm_patch``/``<name>_pas_patch`` on the explicit ones.
+    Without it the kernel keeps its family's name (``pasm_conv``,
+    ``pas_conv``, ``pasm_matmul``, ``pas_matmul``).
     """
     if pool_impl not in POOL_IMPLS:
         raise ValueError(f"pool_impl must be one of {POOL_IMPLS}, got {pool_impl!r}")
@@ -835,13 +842,15 @@ def conv2d(
 
         geom = conv_geom(conv, ih, iw, pool=pool if fuse_pool else 1)
         t = params.gemm_tensor(conv.layout)
-        f = _kops.pasm_conv2d if eng == "kernel_implicit" else _kops.pas_conv2d
+        f, kind = ((_kops.pasm_conv2d, "pasm") if eng == "kernel_implicit"
+                   else (_kops.pas_conv2d, "pas"))
+        named = {"name": f"{name}_{kind}"} if name else {}
         # resolve the budget here (not in the kernel wrappers) so per-call
         # overrides AND the module default both reach the slab planner
         y = f(xb, t, geom, bias=bias, relu=conv.relu, interpret=interpret,
               mesh=mesh,
               vmem_budget=(vmem_budget if vmem_budget is not None
-                           else _IMPLICIT_VMEM_BUDGET))
+                           else _IMPLICIT_VMEM_BUDGET), **named)
         y = y.reshape(-1, conv.c_out)  # (B, P, M) → (B·P, M), after the kernel
         if fuse_pool:  # the kernel already stored the pooled map
             out = _col2im(y, conv, xb.shape[0], geom.ohp, geom.owp, squeeze)
@@ -871,9 +880,11 @@ def conv2d(
         t = params.gemm_tensor(conv.layout)
         if params.pad_k:
             patches = jnp.pad(patches, ((0, 0), (0, params.pad_k)))
-        f = _kops.pasm_matmul if eng == "kernel" else _kops.pas_matmul
+        f, kind = ((_kops.pasm_matmul, "pasm") if eng == "kernel"
+                   else (_kops.pas_matmul, "pas"))
+        named = {"name": f"{name}_{kind}_patch"} if name else {}
         y = f(patches, t, bias=bias, relu=conv.relu, interpret=interpret,
-              mesh=mesh, pool=pool if fuse_pool else 1)
+              mesh=mesh, pool=pool if fuse_pool else 1, **named)
     if fuse_pool:
         out = _col2im(y, conv, xb.shape[0], oh // pool, ow // pool, squeeze)
     else:

@@ -18,6 +18,11 @@ K/groups alone, so every output element sees the identical accumulation
 order on any mesh — sharded outputs are bit-exact vs single-device
 (DESIGN.md §4.1).  Codebooks (and the PAS formulation's in-kernel bin
 counters) stay per-shard-replicated; bias follows the N sharding.
+
+Every public wrapper also takes ``name=``: the kernel's name in the compiled
+program and in a profiler trace (``pallas_call(name=)``; XLA appends
+``.<n>``).  It defaults to the kernel family: ``pasm_matmul``,
+``pas_matmul``, ``pasm_conv``, ``pas_conv``.
 """
 from __future__ import annotations
 
@@ -400,12 +405,12 @@ def _pad_operands(x, idx, codebook, bm, bn, gs_pad, packed):
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "packed", "logical_k", "interpret", "use_ref", "relu", "pool"
+        "packed", "logical_k", "interpret", "use_ref", "relu", "pool", "name"
     ),
 )
 def _pasm_matmul_fwd_impl(
     x, idx, codebook, bias=None, *, packed, logical_k, interpret, use_ref,
-    relu=False, pool=1,
+    name, relu=False, pool=1,
 ):
     if use_ref:
         y = _ref.pasm_matmul_ref(x, idx, codebook, packed=packed)
@@ -434,12 +439,13 @@ def _pasm_matmul_fwd_impl(
         relu=relu,
         pool=pool,
         interpret=interpret,
+        name=name,
     )
     return out[: M // (pool * pool), :N]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _pasm_matmul(x, idx, codebook, packed, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _pasm_matmul(x, idx, codebook, packed, interpret, name):
     logical_k = x.shape[-1]
     return _pasm_matmul_fwd_impl(
         x,
@@ -449,11 +455,13 @@ def _pasm_matmul(x, idx, codebook, packed, interpret):
         logical_k=logical_k,
         interpret=interpret,
         use_ref=False,
+        name=name,
     )
 
 
-def _pasm_fwd(x, idx, codebook, packed, interpret):
-    return _pasm_matmul(x, idx, codebook, packed, interpret), (x, idx, codebook)
+def _pasm_fwd(x, idx, codebook, packed, interpret, name):
+    y = _pasm_matmul(x, idx, codebook, packed, interpret, name)
+    return y, (x, idx, codebook)
 
 
 def _pasm_bwd(packed, interpret, res, g):
@@ -474,11 +482,15 @@ def _pasm_bwd(packed, interpret, res, g):
     return dx, None, dcb.astype(codebook.dtype)
 
 
-_pasm_matmul.defvjp(_pasm_fwd, _pasm_bwd)
+_pasm_matmul.defvjp(
+    _pasm_fwd,
+    lambda packed, interpret, name, res, g: _pasm_bwd(packed, interpret, res, g),
+)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _pasm_matmul_ep(x, idx, codebook, bias, packed, interpret, relu, pool):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _pasm_matmul_ep(x, idx, codebook, bias, packed, interpret, relu, pool,
+                    name):
     """The fused-epilogue variant: bias/ReLU (and the ``pool`` max-reduce
     over window-major rows) applied inside the kernel."""
     return _pasm_matmul_fwd_impl(
@@ -492,17 +504,19 @@ def _pasm_matmul_ep(x, idx, codebook, bias, packed, interpret, relu, pool):
         use_ref=False,
         relu=relu,
         pool=pool,
+        name=name,
     )
 
 
-def _pasm_ep_fwd(x, idx, codebook, bias, packed, interpret, relu, pool):
-    y = _pasm_matmul_ep(x, idx, codebook, bias, packed, interpret, relu, pool)
+def _pasm_ep_fwd(x, idx, codebook, bias, packed, interpret, relu, pool, name):
+    y = _pasm_matmul_ep(x, idx, codebook, bias, packed, interpret, relu, pool,
+                        name)
     # y is a residual only for the ReLU mask (pool == 1: the pooled output
     # can't recover the pre-pool mask — the backward recomputes it instead)
     return y, (x, idx, codebook, bias, y if relu and pool == 1 else None)
 
 
-def _pasm_ep_bwd(packed, interpret, relu, pool, res, g):
+def _pasm_ep_bwd(packed, interpret, relu, pool, name, res, g):
     x, idx, codebook, bias, y = res
     if pool > 1:
         # the fused forward never materializes the pre-pool activations —
@@ -535,6 +549,7 @@ def pasm_matmul(
     interpret: Optional[bool] = None,
     mesh=None,
     pool: int = 1,
+    name: str = "pasm_matmul",
 ) -> jax.Array:
     """``x @ t`` with the fused dequant kernel.  x: (..., K) → (..., N) f32.
 
@@ -567,12 +582,12 @@ def pasm_matmul(
             return _shard_gemm(
                 mesh, N,
                 lambda xl, il, cl, bl: _pasm_matmul_ep(
-                    xl, il, cl, bl, t.packed, interpret, relu, pool
+                    xl, il, cl, bl, t.packed, interpret, relu, pool, name
                 ),
                 (x2, t.idx, t.codebook), x_rank=2, out_rank=2, bias=b,
             )
         return _pasm_matmul_ep(
-            x2, t.idx, t.codebook, b, t.packed, interpret, relu, pool
+            x2, t.idx, t.codebook, b, t.packed, interpret, relu, pool, name
         )
     if mesh is not None:
         nd, _ = _mesh_sizes(mesh)
@@ -584,7 +599,7 @@ def pasm_matmul(
             y = _shard_gemm(
                 mesh, N,
                 lambda xl, il, cl: _pasm_matmul(
-                    xl, il, cl, t.packed, interpret
+                    xl, il, cl, t.packed, interpret, name
                 ),
                 (x2, t.idx, t.codebook), x_rank=2, out_rank=2,
             )
@@ -593,24 +608,25 @@ def pasm_matmul(
             y = _shard_gemm(
                 mesh, N,
                 lambda xl, il, cl, bl: _pasm_matmul_ep(
-                    xl, il, cl, bl, t.packed, interpret, relu, 1
+                    xl, il, cl, bl, t.packed, interpret, relu, 1, name
                 ),
                 (x2, t.idx, t.codebook), x_rank=2, out_rank=2, bias=b,
             )
         return y[:M].reshape(*lead, N)
     if bias is None and not relu:
-        y = _pasm_matmul(x2, t.idx, t.codebook, t.packed, interpret)
+        y = _pasm_matmul(x2, t.idx, t.codebook, t.packed, interpret, name)
     else:
         b = jnp.zeros((N,), jnp.float32) if bias is None else bias
         y = _pasm_matmul_ep(
-            x2, t.idx, t.codebook, b, t.packed, interpret, relu, 1
+            x2, t.idx, t.codebook, b, t.packed, interpret, relu, 1, name
         )
     return y.reshape(*lead, N)
 
 
-@functools.partial(jax.jit, static_argnames=("relu", "pool", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("relu", "pool", "interpret", "name"))
 def _pas_matmul_impl(x, idx, codebook, bias=None, *, relu=False, pool=1,
-                     interpret):
+                     interpret, name):
     M, K = x.shape
     N = idx.shape[1]
     bm, bn, bk, gs_pad = _pick_blocks(M, K, N, K, packed=False)
@@ -624,7 +640,7 @@ def _pas_matmul_impl(x, idx, codebook, bias=None, *, relu=False, pool=1,
         bias_row = bias_row.reshape(1, -1)
     out = pas_matmul_kernel_call(
         xp, idxp, cbp, bias_row, bm=bm, bn=bn, bk=bk, relu=relu, pool=pool,
-        interpret=interpret,
+        interpret=interpret, name=name,
     )
     return out[: M // (pool * pool), :N]
 
@@ -638,6 +654,7 @@ def pas_matmul(
     interpret: Optional[bool] = None,
     mesh=None,
     pool: int = 1,
+    name: str = "pas_matmul",
 ) -> jax.Array:
     """Paper-faithful PASM two-phase matmul (single dictionary).
 
@@ -662,19 +679,22 @@ def pas_matmul(
                 return _shard_gemm(
                     mesh, N,
                     lambda xl, il, cl: _pas_matmul_impl(
-                        xl, il, cl, relu=relu, pool=pool, interpret=interpret
+                        xl, il, cl, relu=relu, pool=pool, interpret=interpret,
+                        name=name,
                     ),
                     (x2, idx, t.codebook), x_rank=2, out_rank=2,
                 )
             return _shard_gemm(
                 mesh, N,
                 lambda xl, il, cl, bl: _pas_matmul_impl(
-                    xl, il, cl, bl, relu=relu, pool=pool, interpret=interpret
+                    xl, il, cl, bl, relu=relu, pool=pool, interpret=interpret,
+                    name=name,
                 ),
                 (x2, idx, t.codebook), x_rank=2, out_rank=2, bias=bias,
             )
         return _pas_matmul_impl(
-            x2, idx, t.codebook, bias, relu=relu, pool=pool, interpret=interpret
+            x2, idx, t.codebook, bias, relu=relu, pool=pool, interpret=interpret,
+            name=name,
         )
     if mesh is not None:
         nd, _ = _mesh_sizes(mesh)
@@ -686,7 +706,8 @@ def pas_matmul(
             y = _shard_gemm(
                 mesh, N,
                 lambda xl, il, cl: _pas_matmul_impl(
-                    xl, il, cl, relu=relu, interpret=interpret
+                    xl, il, cl, relu=relu, interpret=interpret,
+                    name=name,
                 ),
                 (x2, idx, t.codebook), x_rank=2, out_rank=2,
             )
@@ -694,12 +715,14 @@ def pas_matmul(
             y = _shard_gemm(
                 mesh, N,
                 lambda xl, il, cl, bl: _pas_matmul_impl(
-                    xl, il, cl, bl, relu=relu, interpret=interpret
+                    xl, il, cl, bl, relu=relu, interpret=interpret,
+                    name=name,
                 ),
                 (x2, idx, t.codebook), x_rank=2, out_rank=2, bias=bias,
             )
         return y[:M].reshape(*lead, N)
-    y = _pas_matmul_impl(x2, idx, t.codebook, bias, relu=relu, interpret=interpret)
+    y = _pas_matmul_impl(x2, idx, t.codebook, bias, relu=relu,
+                         interpret=interpret, name=name)
     return y.reshape(*lead, N)
 
 
@@ -733,10 +756,10 @@ def _geom_patches(x, geom: ConvGeom):
 @functools.partial(
     jax.jit,
     static_argnames=("geom", "packed", "interpret", "relu", "use_pas",
-                     "vmem_budget"),
+                     "vmem_budget", "name"),
 )
 def _conv_fwd_impl(
-    x, idx, codebook, bias=None, *, geom, packed, interpret=False,
+    x, idx, codebook, bias=None, *, geom, packed, name, interpret=False,
     relu=False, use_pas=False, vmem_budget=None,
 ):
     """Shared implicit-conv forward: tile plan + weight padding + image
@@ -750,6 +773,7 @@ def _conv_fwd_impl(
     row-band slabs when the whole image would blow ``vmem_budget``
     (:func:`conv_slab_plan`).  The kernel returns pooled *wide* pixels per
     slab; the wide columns (``c ≥ owp``) and pad rows are dropped here.
+    ``name`` names the kernel.
     """
     G, _ = codebook.shape
     K = idx.shape[0] * (2 if packed else 1)
@@ -774,12 +798,13 @@ def _conv_fwd_impl(
         out = pas_conv_kernel_call(
             xs, idxp, cbp, bias_row, geom=geom, plan=plan, gs=gs,
             gs_pad=gs_pad, bn=bn, bk=bk, relu=relu, interpret=interpret,
+            name=name,
         )
     else:
         out = pasm_conv_kernel_call(
             xs, idxp, cbp, bias_row, geom=geom, plan=plan, packed=packed,
             gs=gs, gs_pad=gs_pad, bn=bn, bk=bk, relu=relu,
-            interpret=interpret,
+            interpret=interpret, name=name,
         )
     B = x.shape[0]
     out = out[:, :, : plan.rows_out * plan.wq, :N]
@@ -838,20 +863,22 @@ def _conv_bwd_core(geom, packed, interpret, relu, res, g):
     return dx, dcb, g2
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _pasm_conv(x, idx, codebook, geom, packed, interpret, vmem_budget):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _pasm_conv(x, idx, codebook, geom, packed, interpret, vmem_budget, name):
     return _conv_fwd_impl(
         x, idx, codebook, geom=geom, packed=packed, interpret=interpret,
-        vmem_budget=vmem_budget,
+        vmem_budget=vmem_budget, name=name,
     )
 
 
-def _pasm_conv_fwd(x, idx, codebook, geom, packed, interpret, vmem_budget):
-    y = _pasm_conv(x, idx, codebook, geom, packed, interpret, vmem_budget)
+def _pasm_conv_fwd(x, idx, codebook, geom, packed, interpret, vmem_budget,
+                   name):
+    y = _pasm_conv(x, idx, codebook, geom, packed, interpret, vmem_budget,
+                   name)
     return y, (x, idx, codebook)
 
 
-def _pasm_conv_bwd(geom, packed, interpret, vmem_budget, res, g):
+def _pasm_conv_bwd(geom, packed, interpret, vmem_budget, name, res, g):
     x, idx, codebook = res
     dx, dcb, _ = _conv_bwd_core(
         geom, packed, interpret, False, (x, idx, codebook, None, None), g
@@ -862,26 +889,27 @@ def _pasm_conv_bwd(geom, packed, interpret, vmem_budget, res, g):
 _pasm_conv.defvjp(_pasm_conv_fwd, _pasm_conv_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _pasm_conv_ep(x, idx, codebook, bias, geom, packed, interpret, relu,
-                  vmem_budget):
+                  vmem_budget, name):
     """The fused-epilogue implicit conv: bias/ReLU applied inside the kernel."""
     return _conv_fwd_impl(
         x, idx, codebook, bias, geom=geom, packed=packed, interpret=interpret,
-        relu=relu, vmem_budget=vmem_budget,
+        relu=relu, vmem_budget=vmem_budget, name=name,
     )
 
 
 def _pasm_conv_ep_fwd(x, idx, codebook, bias, geom, packed, interpret, relu,
-                      vmem_budget):
+                      vmem_budget, name):
     y = _pasm_conv_ep(x, idx, codebook, bias, geom, packed, interpret, relu,
-                      vmem_budget)
+                      vmem_budget, name)
     # y is a residual only for the ReLU mask (and only when unpooled — the
     # pooled output can't recover the pre-pool mask; the backward recomputes)
     return y, (x, idx, codebook, bias, y if relu and geom.pool == 1 else None)
 
 
-def _pasm_conv_ep_bwd(geom, packed, interpret, relu, vmem_budget, res, g):
+def _pasm_conv_ep_bwd(geom, packed, interpret, relu, vmem_budget, name, res,
+                      g):
     x, idx, codebook, bias, y = res
     dx, dcb, g2 = _conv_bwd_core(
         geom, packed, interpret, relu, (x, idx, codebook, bias, y), g
@@ -904,6 +932,7 @@ def pasm_conv2d(
     mesh=None,
     vmem_budget: Optional[int] = None,
     gather_output: bool = True,
+    name: str = "pasm_conv",
 ) -> jax.Array:
     """Implicit-GEMM conv on the fused-dequant kernel: ``(B, img) → (B, P, N)``.
 
@@ -941,7 +970,7 @@ def pasm_conv2d(
             return _shard_gemm(
                 mesh, t.shape[1],
                 lambda xl, il, cl: _pasm_conv(
-                    xl, il, cl, geom, t.packed, interpret, vmem_budget
+                    xl, il, cl, geom, t.packed, interpret, vmem_budget, name
                 ),
                 (x, t.idx, t.codebook), x_rank=4, out_rank=3,
                 gather_output=gather_output,
@@ -951,6 +980,7 @@ def pasm_conv2d(
             mesh, t.shape[1],
             lambda xl, il, cl, bl: _pasm_conv_ep(
                 xl, il, cl, bl, geom, t.packed, interpret, relu, vmem_budget,
+                name,
             ),
             (x, t.idx, t.codebook), x_rank=4, out_rank=3, bias=b,
             gather_output=gather_output,
@@ -959,11 +989,12 @@ def pasm_conv2d(
     # pooled (argmax-routed) backward
     if bias is None and not relu and geom.pool == 1:
         return _pasm_conv(
-            x, t.idx, t.codebook, geom, t.packed, interpret, vmem_budget
+            x, t.idx, t.codebook, geom, t.packed, interpret, vmem_budget, name
         )
     b = jnp.zeros((t.shape[1],), jnp.float32) if bias is None else bias
     return _pasm_conv_ep(
         x, t.idx, t.codebook, b, geom, t.packed, interpret, relu, vmem_budget,
+        name,
     )
 
 
@@ -978,6 +1009,7 @@ def pas_conv2d(
     mesh=None,
     vmem_budget: Optional[int] = None,
     gather_output: bool = True,
+    name: str = "pas_conv",
 ) -> jax.Array:
     """Implicit-GEMM conv on the paper-faithful two-phase PAS formulation.
 
@@ -1001,7 +1033,7 @@ def pas_conv2d(
                 mesh, t.shape[1],
                 lambda xl, il, cl: _conv_fwd_impl(
                     xl, il, cl, geom=geom, packed=False, interpret=interpret,
-                    relu=relu, use_pas=True, vmem_budget=vmem_budget,
+                    relu=relu, use_pas=True, vmem_budget=vmem_budget, name=name,
                 ),
                 (x, idx, t.codebook), x_rank=4, out_rank=3,
                 gather_output=gather_output,
@@ -1010,14 +1042,14 @@ def pas_conv2d(
             mesh, t.shape[1],
             lambda xl, il, cl, bl: _conv_fwd_impl(
                 xl, il, cl, bl, geom=geom, packed=False, interpret=interpret,
-                relu=relu, use_pas=True, vmem_budget=vmem_budget,
+                relu=relu, use_pas=True, vmem_budget=vmem_budget, name=name,
             ),
             (x, idx, t.codebook), x_rank=4, out_rank=3, bias=bias,
             gather_output=gather_output,
         )
     return _conv_fwd_impl(
         x, idx, t.codebook, bias, geom=geom, packed=False, interpret=interpret,
-        relu=relu, use_pas=True, vmem_budget=vmem_budget,
+        relu=relu, use_pas=True, vmem_budget=vmem_budget, name=name,
     )
 
 

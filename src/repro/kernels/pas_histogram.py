@@ -101,6 +101,7 @@ def pas_matmul_kernel_call(
     relu: bool = False,
     pool: int = 1,
     interpret: bool = False,
+    name: str = "pas_matmul",
 ) -> jax.Array:
     """``x (M,K) · idx (K,N) · codebook (1,B) → (M,N) f32`` (single dictionary).
 
@@ -138,10 +139,11 @@ def pas_matmul_kernel_call(
         scratch_shapes=[pltpu.VMEM((B, bm, bn), jnp.float32)],
         compiler_params=_params(3),
         interpret=interpret,
+        name=name,
     )(*operands)
 
 
-def _conv_kernel(
+def _pas_conv_kernel(
     x_ref, idx_ref, cb_ref, *rest, geom: ConvGeom, plan: SlabPlan,
     n_k: int, relu: bool, bk: int, gs: int, gs_pad: int,
 ):
@@ -178,6 +180,7 @@ def pas_conv_kernel_call(
     bk: int = 512,
     relu: bool = False,
     interpret: bool = False,
+    name: str = "pas_conv",
 ) -> jax.Array:
     """Implicit-GEMM conv on the paper-faithful two-phase formulation.
 
@@ -208,7 +211,7 @@ def pas_conv_kernel_call(
 
     return pl.pallas_call(
         functools.partial(
-            _conv_kernel, geom=geom, plan=plan, n_k=n_k, relu=relu,
+            _pas_conv_kernel, geom=geom, plan=plan, n_k=n_k, relu=relu,
             bk=bk, gs=gs, gs_pad=gs_pad,
         ),
         grid=(B_img, n_slabs * plan.n_blocks, Np // bn, n_k),
@@ -222,4 +225,5 @@ def pas_conv_kernel_call(
         ],
         compiler_params=_params(4),
         interpret=interpret,
+        name=name,
     )(*operands)

@@ -290,6 +290,7 @@ def pasm_matmul_kernel_call(
     relu: bool = False,
     pool: int = 1,
     interpret: bool = False,
+    name: str = "pasm_matmul",
 ) -> jax.Array:
     """Raw pallas_call; shape plumbing/padding lives in :mod:`repro.kernels.ops`.
 
@@ -337,6 +338,7 @@ def pasm_matmul_kernel_call(
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)] if pool > 1 else [],
         compiler_params=_params(3),
         interpret=interpret,
+        name=name,
     )(*operands)
 
 
@@ -449,7 +451,7 @@ def conv_out_spec(plan: SlabPlan, bn: int):
                         lambda b, i, j, k: (b, i // nb, i % nb, j))
 
 
-def _conv_kernel(
+def _pasm_conv_kernel(
     x_ref, idx_ref, cb_ref, *rest, geom: ConvGeom, plan: SlabPlan,
     packed: bool, n_k: int, relu: bool, bk: int, gs: int, gs_pad: int,
 ):
@@ -494,6 +496,7 @@ def pasm_conv_kernel_call(
     bk: int = 512,
     relu: bool = False,
     interpret: bool = False,
+    name: str = "pasm_conv",
 ) -> jax.Array:
     """Implicit-GEMM conv pallas_call over the phase-layout image.
 
@@ -532,7 +535,7 @@ def pasm_conv_kernel_call(
 
     return pl.pallas_call(
         functools.partial(
-            _conv_kernel, geom=geom, plan=plan, packed=packed, n_k=n_k,
+            _pasm_conv_kernel, geom=geom, plan=plan, packed=packed, n_k=n_k,
             relu=relu, bk=bk, gs=gs, gs_pad=gs_pad,
         ),
         grid=(B_img, n_slabs * plan.n_blocks, Np // bn, n_k),
@@ -543,4 +546,5 @@ def pasm_conv_kernel_call(
         scratch_shapes=scratch,
         compiler_params=_params(4),
         interpret=interpret,
+        name=name,
     )(*operands)

@@ -47,12 +47,17 @@ def print_rollup(roll: dict, slots: int) -> None:
     for kind, rate in (("lm", "tok_s"), ("cnn", "img_s")):
         if not roll[f"{kind}_n"]:
             continue
+        if kind == "lm":
+            extra = (f"ttft p50={_fmt(roll['lm_p50_ttft_s'], 's')} "
+                     f"p99={_fmt(roll['lm_p99_ttft_s'], 's')}")
+        else:
+            extra = (f"queue p50={_fmt(roll['cnn_p50_queue_s'], 's')} "
+                     f"p99={_fmt(roll['cnn_p99_queue_s'], 's')}  "
+                     f"batch fill={_fmt(roll['cnn_batch_fill'])}")
         print(f"[serve]   {kind}: n={roll[f'{kind}_n']}  "
               f"latency p50={_fmt(roll[f'{kind}_p50_latency_s'], 's')} "
               f"p99={_fmt(roll[f'{kind}_p99_latency_s'], 's')}  "
-              f"ttft p50={_fmt(roll[f'{kind}_p50_ttft_s'], 's')} "
-              f"p99={_fmt(roll[f'{kind}_p99_ttft_s'], 's')}  "
-              f"{rate}={_fmt(roll[rate])}")
+              f"{extra}  {rate}={_fmt(roll[rate])}")
     if roll["slo_met"] or roll["slo_missed"]:
         print(f"[serve]   SLO: {roll['slo_met']} met, {roll['slo_missed']} missed")
     # failure-mode rollup (DESIGN.md §2.4) — only when something tripped

@@ -217,17 +217,26 @@ def forward(
     ``cfg.vmem_budget`` bounds the implicit engines' per-image VMEM
     footprint: larger images stream through the kernel as row-band slabs,
     bit-exact (DESIGN.md §3.3).
+
+    Stage ``i`` (from 1) names its kernel ``conv<i>_<engine kind>``
+    (``conv2d(name=)``), so a profiler trace shows ``conv1_pasm`` … per
+    stage.  The stage also runs under ``jax.named_scope("conv<i>")`` and the
+    classifier head under ``"head"``: every relayout, pad and head op then
+    carries its stage in the HLO ``op_name`` metadata, which the compiled
+    program's text and a profile taken with HLO protos show.
     """
     if cfg.impl not in _IMPLS:
         raise ValueError(
             f"impl must be one of {'|'.join(_IMPLS)}, got {cfg.impl!r}"
         )
     x = images
-    for p, (conv, pool) in zip(params["conv"], stages(cfg)):
-        x = _conv.conv2d(x, p, conv, engine=cfg.impl, interpret=interpret,
-                         mesh=mesh, vmem_budget=cfg.vmem_budget, pool=pool,
-                         pool_impl=cfg.pool_impl)
-    return _head(x, params["head"], mesh=mesh)
+    for i, (p, (conv, pool)) in enumerate(zip(params["conv"], stages(cfg)), 1):
+        with jax.named_scope(f"conv{i}"):
+            x = _conv.conv2d(x, p, conv, engine=cfg.impl, interpret=interpret,
+                             mesh=mesh, vmem_budget=cfg.vmem_budget, pool=pool,
+                             pool_impl=cfg.pool_impl, name=f"conv{i}")
+    with jax.named_scope("head"):
+        return _head(x, params["head"], mesh=mesh)
 
 
 def forward_dense(

@@ -16,12 +16,23 @@ the image-classification side and the loop that serves both:
   share the process continuously — neither waits for the other to drain.
 
 Metrics ride the same :class:`repro.serve.metrics.Metrics` rollup (img/s,
-p50/p99 latency) using ``"cnn-<n>"`` uids so a shared Metrics instance never
-collides with the engine's integer LM uids.
+p50/p99 latency and queue wait, batch fill) using ``"cnn-<n>"`` uids so a
+shared Metrics instance never collides with the engine's integer LM uids.
+A classifier has no first token, so CNN requests carry no ttft.
+
+``CnnBatcher.flush`` records profiler spans (``jax.profiler.TraceAnnotation``,
+a microsecond or two of host time each while no trace is being taken):
+``cnn.flush`` around the whole flush and, for each classify call in turn,
+``cnn.pad`` (host zero-pad into the batch buffer), ``cnn.put`` (host to
+device), ``cnn.call`` (the classify closure), ``cnn.argmax`` (dispatch of
+the class argmax) and ``cnn.readback`` (the blocking copy of the classes to
+the host).  Each per-call span carries ``call`` (the running call index)
+and ``rows`` (the images it carries).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 import warnings
 from collections import deque
@@ -125,31 +136,53 @@ class CnnBatcher:
         return r
 
     def flush(self) -> List[CnnRequest]:
-        """Serve every waiting image: group by bucket, pad, classify."""
-        by_bucket: Dict[Tuple[int, int], List[CnnRequest]] = {}
-        while self.waiting:
-            r = self.waiting.popleft()
-            by_bucket.setdefault(r.bucket, []).append(r)
-        served: List[CnnRequest] = []
-        for bucket, reqs in by_bucket.items():
-            bh, bw = bucket
-            C = self.cfg.in_chw[0]
-            for i in range(0, len(reqs), self.max_batch):
-                chunk = reqs[i : i + self.max_batch]
-                imgs = np.zeros((self.max_batch, C, bh, bw), np.float32)
-                for j, r in enumerate(chunk):
-                    h, w = r.image.shape[1:]
-                    imgs[j, :, :h, :w] = r.image
-                    self.metrics.mark_admit(r.uid)
-                logits = self._classify_fn(bucket)(self.params, jnp.asarray(imgs))
-                cls = np.asarray(jnp.argmax(logits, axis=-1))
-                for j, r in enumerate(chunk):
-                    r.cls = int(cls[j])
-                    r.done = True
-                    self.metrics.mark_first(r.uid)
-                    self.metrics.mark_done(r.uid, 1)
-                served.extend(chunk)
+        """Serve every waiting image: group by bucket, pad, classify.
+
+        Each classify call counts into the metrics' ``cnn_calls``,
+        ``cnn_images`` and ``cnn_rows`` (the batch rows it carried, padding
+        included)."""
+        with jax.profiler.TraceAnnotation("cnn.flush"):
+            by_bucket: Dict[Tuple[int, int], List[CnnRequest]] = {}
+            while self.waiting:
+                r = self.waiting.popleft()
+                by_bucket.setdefault(r.bucket, []).append(r)
+            served: List[CnnRequest] = []
+            for bucket, reqs in by_bucket.items():
+                for i in range(0, len(reqs), self.max_batch):
+                    chunk = reqs[i : i + self.max_batch]
+                    self._classify_chunk(bucket, chunk)
+                    served.extend(chunk)
         return served
+
+    def _classify_chunk(self, bucket: Tuple[int, int], chunk: List[CnnRequest]):
+        """One classify call: pad, put, call, argmax, read back, each its
+        own span."""
+        (bh, bw), m = bucket, self.metrics
+        span = functools.partial(jax.profiler.TraceAnnotation,
+                                 call=m.counters.get("cnn_calls", 0),
+                                 rows=len(chunk))
+        with span("cnn.pad"):
+            imgs = np.zeros((self.max_batch, self.cfg.in_chw[0], bh, bw),
+                            np.float32)
+            for j, r in enumerate(chunk):
+                h, w = r.image.shape[1:]
+                imgs[j, :, :h, :w] = r.image
+                m.mark_admit(r.uid)
+        with span("cnn.put"):
+            x = jnp.asarray(imgs)
+        with span("cnn.call"):
+            logits = self._classify_fn(bucket)(self.params, x)
+        with span("cnn.argmax"):
+            cls = jnp.argmax(logits, axis=-1)
+        with span("cnn.readback"):
+            cls = np.asarray(cls)
+        m.incr("cnn_calls")
+        m.incr("cnn_images", len(chunk))
+        m.incr("cnn_rows", self.max_batch)
+        for j, r in enumerate(chunk):
+            r.cls = int(cls[j])
+            r.done = True
+            m.mark_done(r.uid, 1)
 
 
 class MixedBatcher:

@@ -1,12 +1,16 @@
 """Per-request serving metrics: timestamps → p50/p99 rollups.
 
 Every request carries a :class:`Timeline` of wall-clock marks
-(queue → admit → first token → done-or-failed).  :class:`Metrics` owns the
-timelines plus slot-occupancy and failure-mode counters and rolls them up
-into the serving numbers the launcher prints and
-``benchmarks/serve_bench.py`` emits as BENCH_serve.json: p50/p99 end-to-end
-latency, p50/p99 time-to-first-token, tok/s, img/s, mean slot occupancy,
-SLO hit/miss counts, the fault-tolerance counters
+(queue → admit → first token → done-or-failed; an image classification has
+no first token).  :class:`Metrics` owns the timelines plus slot-occupancy,
+batching and failure-mode counters and rolls them up into the serving
+numbers the launcher prints and ``benchmarks/serve_bench.py`` emits as
+BENCH_serve.json: p50/p99 end-to-end latency, p50/p99 time-to-first-token
+(LM), p50/p99 queue wait and batch fill of the image classifier
+(``cnn_batch_fill``: images over the batch rows its calls carried, from the
+``cnn_images``/``cnn_rows`` counters that
+:class:`repro.serve.batcher.CnnBatcher` increments), tok/s, img/s, mean slot
+occupancy, SLO hit/miss counts, the fault-tolerance counters
 (``n_rejected``/``n_shed``/``n_evicted_deadline``/``n_quarantined``/
 ``n_retried``/``n_degraded``), and per-failure-kind latency rows
 (``failed_<kind>_{n,p50,p99}_latency_s``).
@@ -48,7 +52,7 @@ class Timeline:
     kind: str  # "lm" | "cnn"
     t_submit: float
     t_admit: float = math.nan
-    t_first: float = math.nan  # first decode token / classification result
+    t_first: float = math.nan  # first decode token (lm only)
     t_done: float = math.nan  # terminal stamp: completion OR failure
     n_out: int = 0  # tokens generated (lm) or images classified (cnn: 1)
     slo_s: Optional[float] = None  # per-request latency budget
@@ -148,8 +152,14 @@ class Metrics:
             out[f"{kind}_n"] = len(ks)
             out[f"{kind}_p50_latency_s"] = percentile(lat, 50)
             out[f"{kind}_p99_latency_s"] = percentile(lat, 99)
-            out[f"{kind}_p50_ttft_s"] = percentile([t.ttft_s for t in ks], 50)
-            out[f"{kind}_p99_ttft_s"] = percentile([t.ttft_s for t in ks], 99)
+            if kind == "lm":
+                ttft = [t.ttft_s for t in ks]
+                out["lm_p50_ttft_s"] = percentile(ttft, 50)
+                out["lm_p99_ttft_s"] = percentile(ttft, 99)
+            else:
+                queue = [t.queue_s for t in ks]
+                out["cnn_p50_queue_s"] = percentile(queue, 50)
+                out["cnn_p99_queue_s"] = percentile(queue, 99)
             if ks:
                 t0 = min(t.t_submit for t in ks)
                 t1 = max(t.t_done for t in ks)
@@ -163,6 +173,9 @@ class Metrics:
         out["mean_occupancy"] = (
             self._occ_sum / self._occ_ticks if self._occ_ticks else math.nan
         )
+        rows = self.counters.get("cnn_rows", 0)
+        out["cnn_batch_fill"] = (self.counters.get("cnn_images", 0) / rows
+                                 if rows else math.nan)
         # -- failure domains (DESIGN.md §2.4) --------------------------------
         for name in FAILURE_COUNTERS:
             out[name] = self.counters.get(name, 0)

@@ -231,6 +231,29 @@ def test_fused_pool_cnn_forward_one_pallas_call_per_stage():
     assert sum("reduce_window" in n for n in names_u) == len(cfg.layers)
 
 
+@pytest.mark.parametrize("engine,family,staged", [
+    ("kernel_implicit", "pasm_conv", "conv3_pasm"),
+    ("pas_kernel_implicit", "pas_conv", "conv3_pas"),
+    ("kernel", "pasm_matmul", "conv3_pasm_patch"),
+    ("pas_kernel", "pas_matmul", "conv3_pas_patch"),
+])
+def test_conv2d_kernel_names(engine, family, staged):
+    """A direct ``conv2d`` call names its kernel after the kernel family; a
+    stage name (``name=``) replaces it with ``<stage>_<engine kind>``."""
+    conv = cv.Conv2D(k=3, c_in=4, c_out=8, stride=1, relu=True)
+    imgs, kern, bias = _mk(conv, hw=(9, 9))
+    shared = cv.ConvParams.quantize(kern, 16, bias=bias)
+
+    def kernel_names(**kw):
+        jx = jax.make_jaxpr(lambda x: cv.conv2d(
+            x, shared, conv, engine=engine, interpret=True, pool=2, **kw))(imgs)
+        return [e.params["name"] for e in _iter_eqns(jx.jaxpr)
+                if e.primitive.name == "pallas_call"]
+
+    assert kernel_names() == [family]
+    assert kernel_names(name="conv3") == [staged]
+
+
 def test_auto_always_implicit_no_explicit_fallback(monkeypatch):
     conv = cv.Conv2D(k=3, c_in=4, c_out=8, stride=1, padding="same")
     imgs, kern, _ = _mk(conv, hw=(9, 9))

@@ -266,6 +266,49 @@ def test_cnn_batcher_buckets_and_pad_equivalence():
         b.submit(rng.standard_normal((3, 64, 64)).astype(np.float32))
 
 
+def test_cnn_flush_spans_and_counters(tmp_path):
+    """``flush`` records one ``cnn.flush`` span holding, per classify call
+    and in order, ``cnn.pad``/``put``/``call``/``argmax``/``readback``, each
+    tagged with the call index and the images it carries; its counters roll
+    up into the batch fill.  11 images at ``max_batch`` 8 make two calls."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    ccfg, cparams = _setup_cnn()
+    b = CnnBatcher(ccfg, cparams, max_batch=8)
+    rng = np.random.default_rng(5)
+    for _ in range(11):
+        b.submit(rng.standard_normal(ccfg.in_chw).astype(np.float32))
+    with jax.profiler.trace(str(tmp_path)):
+        served = b.flush()
+    assert len(served) == 11 and all(r.done for r in served)
+
+    (path,) = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    spans = sorted(
+        ((e.start_ns, e.end_ns, e.name, dict(e.stats))
+         for plane in ProfileData.from_file(path).planes
+         if plane.name == "/host:CPU"
+         for line in plane.lines for e in line.events
+         if e.name.startswith("cnn.")),
+        key=lambda s: s[0])
+    (flush,) = [s for s in spans if s[2] == "cnn.flush"]
+    phases = [s for s in spans if s[2] != "cnn.flush"]
+    order = ["cnn.pad", "cnn.put", "cnn.call", "cnn.argmax", "cnn.readback"]
+    assert [s[2] for s in phases] == order * 2
+    assert all(flush[0] <= s[0] and s[1] <= flush[1] for s in phases)
+    assert all(a[1] <= b[0] for a, b in zip(phases, phases[1:]))
+    assert [s[3] for s in phases] == (
+        [{"call": 0, "rows": 8}] * 5 + [{"call": 1, "rows": 3}] * 5)
+
+    roll = b.metrics.rollup()
+    assert b.metrics.counters["cnn_calls"] == 2
+    assert b.metrics.counters["cnn_images"] == 11
+    assert roll["cnn_batch_fill"] == 11 / 16
+    assert roll["cnn_p50_queue_s"] >= 0 and roll["cnn_p99_queue_s"] >= 0
+    assert "cnn_p50_ttft_s" not in roll  # a classifier has no first token
+
+
 def test_mixed_lm_cnn_traffic_drains_both():
     cfg, params = _setup("stablelm-3b")
     ccfg, cparams = _setup_cnn()

@@ -14,6 +14,7 @@ a compile for a described chip is written to it but cannot be read back
 without one.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -109,6 +110,22 @@ def test_alexnet_forward_compiles(one_chip, impl):
         _on(params, one_chip), x,
     )
     assert hlo.count("tpu_custom_call") >= len(cfg.layers)
+    # every stage's kernel carries its stage name, not the jitted wrapper's
+    kind = "pas" if impl == "pas_kernel_implicit" else "pasm"
+    calls = [line.split(" = ", 1)[0].strip().lstrip("%")
+             for line in hlo.splitlines() if "tpu_custom_call" in line
+             and " = " in line]
+    for i in range(1, len(cfg.layers) + 1):
+        named = [c for c in calls if re.fullmatch(rf"conv{i}_{kind}(\.\d+)?", c)]
+        assert len(named) == 1, (i, calls)
+    assert not any(c.startswith("_conv_fwd_impl") for c in calls), calls
+    # the relayout, pad and head ops carry their stage in the HLO metadata
+    # (named scopes), so a compiled program or a profile with HLO protos
+    # reads per stage; no op of the forward is left outside a scope
+    scopes = {path.split("/")[0] for path in
+              re.findall(r'op_name="jit\([^)]*\)/([^"]*)"', hlo)}
+    assert scopes == {f"conv{i}" for i in range(1, len(cfg.layers) + 1)} | {
+        "head"}, scopes
 
 
 def test_slabbed_implicit_conv_compiles(one_chip):
