@@ -135,9 +135,8 @@ def _slab_info(t_gemm, geom, ih, iw) -> dict:
     hp, wp = ih + plh + phh, iw + plw + phw
     K, N = t_gemm.shape
     G, B = t_gemm.codebook.shape
-    _, bn, bk, _ = ops._pick_blocks(geom.P_rows, K, N, K // G, t_gemm.packed)
-    plan = ops.conv_slab_plan(geom, hp, wp, bn=bn, bk=bk, bins=B,
-                              packed=t_gemm.packed)
+    plan = ops.conv_tile_plan(geom, hp, wp, k=K, n=N, groups=G, bins=B,
+                              packed=t_gemm.packed).plan
     return {"slab_rows": plan.band_rows, "n_slabs": plan.n_slabs}
 
 

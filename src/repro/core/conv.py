@@ -224,9 +224,10 @@ def _implicit_fits(
     per-grid-step VMEM block — idx / codebook / bias / (pooled) output
     block, their double buffers, and the pool (or PAS bin) scratch —
     fits ``budget`` (:func:`repro.kernels.ops.conv_whole_image_fits`,
-    audited against the kernels' BlockSpecs).  The seed counted only one
-    copy of the raw image bytes, under-reporting residency by the pipeline
-    double buffer and the whole fixed-block overhead.
+    audited against the kernels' BlockSpecs), at the output-channel block
+    the kernel takes (:func:`repro.kernels.ops.conv_tile_plan`).  The seed
+    counted only one copy of the raw image bytes, under-reporting residency
+    by the pipeline double buffer and the whole fixed-block overhead.
 
     Shapes that fail no longer fall back to explicit im2col: ``auto``
     keeps the implicit engine and the kernel wrappers stream the image as
@@ -255,12 +256,12 @@ def _implicit_fits(
     groups = params.groups if params is not None else 1
     bins = params.bins if params is not None else 16
     has_bias = params is None or params.bias is not None
-    K = conv.K + pad_k
-    _, bn, bk, _ = _kops._pick_blocks(
-        geom.P_rows, K, conv.c_out, K // groups, packed
+    tp = _kops.conv_tile_plan(
+        geom, hp, wp, k=conv.K + pad_k, n=conv.c_out, groups=groups,
+        bins=bins, packed=packed, has_bias=has_bias, vmem_budget=budget,
     )
     return _kops.conv_whole_image_fits(
-        geom, hp, wp, bn=bn, bk=bk, bins=bins, packed=packed,
+        geom, hp, wp, bn=tp.bn_conv, bk=tp.bk, bins=bins, packed=packed,
         pas=False, has_bias=has_bias, vmem_budget=budget,
     )
 

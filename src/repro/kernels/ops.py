@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -53,7 +53,9 @@ __all__ = [
     "pas_conv2d",
     "ConvGeom",
     "SlabPlan",
+    "ConvTilePlan",
     "conv_slab_plan",
+    "conv_tile_plan",
     "conv_whole_image_fits",
     "IMPLICIT_VMEM_BUDGET",
     "matmul_flops",
@@ -342,6 +344,67 @@ def conv_slab_plan(
         rows -= 1
     n_slabs = -(-ohp // rows)
     return _plan_rows(geom, wq, -(-ohp // n_slabs), pas)
+
+
+class ConvTilePlan(NamedTuple):
+    """One implicit conv stage's whole tile plan (:func:`conv_tile_plan`).
+
+    ``bk``/``gs_pad`` are the k-tile plan (:func:`_pick_blocks`, shared with
+    the explicit GEMM); ``bn_conv`` is the output-channel block of one grid
+    step, fed ``chunks_per_tile`` 128-lane chunks from one assembled patch
+    tile; ``tiles_per_call`` counts the grid steps — patch-tile assemblies —
+    of one call at the planned batch; ``plan`` is the image plan at
+    ``bn_conv``.
+    """
+
+    bk: int
+    gs_pad: int
+    bn_conv: int
+    chunks_per_tile: int
+    tiles_per_call: int
+    plan: SlabPlan
+
+
+def conv_tile_plan(
+    geom: ConvGeom, hp: int, wp: int, *, k: int, n: int, groups: int,
+    bins: int, packed: bool = False, pas: bool = False, has_bias: bool = True,
+    vmem_budget: Optional[int] = None, itemsize: int = 4, batch: int = 1,
+) -> ConvTilePlan:
+    """THE implicit conv planner, shared by ``_conv_fwd_impl`` (dispatch),
+    :func:`repro.core.conv._implicit_fits` (planner) and
+    :func:`conv_hbm_bytes` (model), so the three cannot drift apart.
+
+    ``k`` is the GEMM reduction (pack-time K-pad included), ``n`` the
+    output channels (the local share under a mesh).  The k-tile plan is
+    :func:`_pick_blocks`'s.  The output-channel block ``bn_conv`` is the
+    largest 128-multiple divisor of the padded ``Np`` whose
+    :func:`conv_slab_plan` (same budget) cuts no more pixel blocks
+    (``n_slabs·n_blocks``) than ``bn = 128`` does: every pixel block
+    assembles its patch tile once per k-step and per output-channel block,
+    so a wider block saves ``Np/bn_conv``-fold assemblies and must never buy
+    them back with more slabs.  The PAS kernel keeps 128 (its bin scratch
+    grows with the block).
+    """
+    _, bn, bk, gs_pad = _pick_blocks(geom.P_rows, k, n, k // groups, packed)
+    n_pad = _round_up(n, bn)
+    blocks = dict(bk=bk, bins=bins, packed=packed, pas=pas,
+                  has_bias=has_bias, vmem_budget=vmem_budget,
+                  itemsize=itemsize)
+    plan = conv_slab_plan(geom, hp, wp, bn=bn, **blocks)
+    pixel_blocks = plan.n_slabs * plan.n_blocks
+    if not pas:
+        for wide in range(n_pad, bn, -LANE):
+            if n_pad % wide:
+                continue
+            cand = conv_slab_plan(geom, hp, wp, bn=wide, **blocks)
+            if cand.n_slabs * cand.n_blocks <= pixel_blocks:
+                bn, plan = wide, cand
+                break
+    tiles = (batch * plan.n_slabs * plan.n_blocks * (n_pad // bn)
+             * (groups * gs_pad // bk))
+    return ConvTilePlan(bk=bk, gs_pad=gs_pad, bn_conv=bn,
+                        chunks_per_tile=bn // LANE, tiles_per_call=tiles,
+                        plan=plan)
 
 
 def _pad_weight_operands(idx, codebook, bn, gs_pad, packed):
@@ -765,11 +828,14 @@ def _conv_fwd_impl(
     """Shared implicit-conv forward: tile plan + weight padding + image
     relayout + kernel call.
 
-    The reduction tiling (``bn``/``bk``/``gs_pad``) is a pure function of
-    K/N/groups in :func:`_pick_blocks`, so the implicit kernel walks the
-    exact k-tile sequence of the explicit path.  The padded image (NHWC
-    moved to channel-major first) is re-laid out into the plan's phase
-    images (:func:`~repro.kernels.pasm_matmul.phase_slabs`) — whole, or as
+    :func:`conv_tile_plan` plans the call: the reduction tiling
+    (``bk``/``gs_pad``) is a pure function of K/N/groups in
+    :func:`_pick_blocks`, so the implicit kernel walks the exact k-tile
+    sequence of the explicit path, and the output-channel block
+    ``bn_conv`` feeds every 128-lane chunk of it from one assembled patch
+    tile.  The padded image (NHWC moved to channel-major first) is re-laid
+    out into the plan's phase images
+    (:func:`~repro.kernels.pasm_matmul.phase_slabs`) — whole, or as
     row-band slabs when the whole image would blow ``vmem_budget``
     (:func:`conv_slab_plan`).  The kernel returns pooled *wide* pixels per
     slab; the wide columns (``c ≥ owp``) and pad rows are dropped here.
@@ -779,16 +845,17 @@ def _conv_fwd_impl(
     K = idx.shape[0] * (2 if packed else 1)
     N = idx.shape[1]
     gs = K // G
-    _, bn, bk, gs_pad = _pick_blocks(geom.P_rows, K, N, gs, packed)
-    idxp, cbp, _ = _pad_weight_operands(idx, codebook, bn, gs_pad, packed)
     xp = _pad_image(x, geom)
     if geom.nhwc:
         xp = jnp.transpose(xp, (0, 3, 1, 2))
-    plan = conv_slab_plan(
-        geom, xp.shape[2], xp.shape[3], bn=bn, bk=bk, bins=codebook.shape[1],
-        packed=packed, pas=use_pas, has_bias=bias is not None,
-        vmem_budget=vmem_budget, itemsize=xp.dtype.itemsize,
+    tp = conv_tile_plan(
+        geom, xp.shape[2], xp.shape[3], k=K, n=N, groups=G,
+        bins=codebook.shape[1], packed=packed, pas=use_pas,
+        has_bias=bias is not None, vmem_budget=vmem_budget,
+        itemsize=xp.dtype.itemsize,
     )
+    bn, bk, gs_pad, plan = tp.bn_conv, tp.bk, tp.gs_pad, tp.plan
+    idxp, cbp, _ = _pad_weight_operands(idx, codebook, bn, gs_pad, packed)
     xs = phase_slabs(xp, geom, plan)
     bias_row = None
     if bias is not None:
@@ -1149,11 +1216,10 @@ def conv_hbm_bytes(
     if implicit:
         (plh, phh), (plw, phw) = geom.pad
         hp, wp = ih + plh + phh, iw + plw + phw
-        plan = conv_slab_plan(
-            geom, hp, wp, bn=bn, bk=bk, bins=B, packed=t.packed,
-            pas=False, has_bias=True, vmem_budget=vmem_budget,
-            itemsize=act_bytes,
-        )
+        plan = conv_tile_plan(
+            geom, hp, wp, k=K, n=N, groups=G, bins=B, packed=t.packed,
+            has_bias=True, vmem_budget=vmem_budget, itemsize=act_bytes,
+        ).plan
         x_bytes = batch * plan.image_elems(geom) * act_bytes
         out_bytes = batch * plan.n_slabs * plan.n_blocks * plan.bmp * Np * 4
     else:

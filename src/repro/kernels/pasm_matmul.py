@@ -30,9 +30,10 @@ Two entry points share the kernel body:
   * :func:`pasm_conv_kernel_call` — **implicit-GEMM convolution**: ``x`` is
     the image batch in the *phase layout* (:func:`phase_slabs`); each patch
     tile is assembled *inside* the kernel (:func:`assemble_tile`), so no
-    ``(B·P, K)`` patch matrix ever exists in HBM.  The k-tile plan is the
-    explicit path's, so every output sees the same dequantized weight tiles
-    in the same k order.
+    ``(B·P, K)`` patch matrix ever exists in HBM, once per grid step for
+    every 128-lane chunk of a wide output-channel block.  The k-tile plan
+    is the explicit path's, so every output sees the same dequantized
+    weight tiles in the same k order.
 """
 from __future__ import annotations
 
@@ -146,6 +147,12 @@ class SlabPlan(NamedTuple):
     while slab ``s`` computes.  ``bmp`` is the pooled wide pixels (lanes) of
     one block, ``lanes`` the flattened length of one slab's phase images
     (blocks plus the lane halo the largest tap offset reaches).
+
+    Each of the ``n_slabs·n_blocks`` pixel blocks assembles its patch tile
+    once per k-step and per output-channel block; the plan is sized at the
+    kernel's output-channel block ``bn_conv``, which
+    :func:`repro.kernels.ops.conv_tile_plan` widens only while the pixel
+    blocks do not multiply.
     """
 
     n_slabs: int
@@ -455,8 +462,10 @@ def _pasm_conv_kernel(
     x_ref, idx_ref, cb_ref, *rest, geom: ConvGeom, plan: SlabPlan,
     packed: bool, n_k: int, relu: bool, bk: int, gs: int, gs_pad: int,
 ):
-    """Implicit-GEMM body: assemble the transposed patch tile, then the same
-    :func:`_fused_dequant_step` as the explicit GEMM."""
+    """Implicit-GEMM body: assemble the transposed patch tile once, then run
+    the explicit GEMM's :func:`_fused_dequant_step` on each 128-lane chunk
+    of the output-channel block — every chunk's contraction has the shape
+    and operands of a ``bn = 128`` grid step, so the outputs are too."""
     pool = geom.pool
     if pool > 1:
         rest, acc_ref = rest[:-1], rest[-1]
@@ -474,11 +483,17 @@ def _pasm_conv_kernel(
     base = (pl.program_id(1) % plan.n_blocks) * plan.bmp
     assemble_tile(x_ref, t_ref, base, k * bk, geom=geom, plan=plan, bk=bk,
                   gs=gs, gs_pad=gs_pad)
-    _fused_dequant_step(
-        t_ref[:bk, :], idx_ref, cb_ref, b_ref, o_ref, acc_ref,
-        k=k, g=k // (gs_pad // bk), n_k=n_k, packed=packed, relu=relu,
-        pool=pool, transposed=True,
-    )
+    lhs = t_ref[:bk, :]
+    for c in range(o_ref.shape[-1] // LANE):
+        cols = slice(c * LANE, (c + 1) * LANE)
+        _fused_dequant_step(
+            lhs, idx_ref.at[:, cols], cb_ref,
+            None if b_ref is None else b_ref.at[:, cols],
+            o_ref.at[..., cols],
+            None if acc_ref is None else acc_ref.at[:, cols],
+            k=k, g=k // (gs_pad // bk), n_k=n_k, packed=packed, relu=relu,
+            pool=pool, transposed=True,
+        )
 
 
 def pasm_conv_kernel_call(
@@ -505,8 +520,15 @@ def pasm_conv_kernel_call(
     f32``: pooled wide pixels per slab (the caller drops the wide columns
     and pad rows).  Grid ``(B, n_slabs·n_blocks, Np/bn, Kp/bk)``; the
     k-tile sequence is the explicit path's (same ``bk``/``gs_pad``), so the
-    dequantized weight tiles and their order match it.  Preconditions
-    (enforced by ops.py): ``gs_pad % bk == 0``, ``Np % bn == 0``, bias
+    dequantized weight tiles and their order match it.
+
+    ``bn`` is the output-channel block (``bn_conv`` of
+    :func:`repro.kernels.ops.conv_tile_plan`, a 128-multiple divisor of
+    ``Np``): each grid step assembles its patch tile once and feeds every
+    128-lane chunk of the block from it, one ``(bk, 128)`` dequant, dot and
+    epilogue per chunk — the contractions of ``bn = 128`` grid steps, so
+    the result does not depend on ``bn``.  Preconditions (enforced by
+    ops.py): ``gs_pad % bk == 0``, ``Np % bn == bn % 128 == 0``, bias
     ``(1, Np)``.
     """
     B_img, n_slabs, _, c_in, _ = x.shape
@@ -515,6 +537,7 @@ def pasm_conv_kernel_call(
     G = codebook.shape[0]
     assert Kp == G * gs_pad, (Kp, G, gs_pad)
     assert gs_pad % bk == 0, (gs_pad, bk)
+    assert Np % bn == 0 and bn % LANE == 0, (Np, bn)
     pw = geom.pool * geom.pool
     n_k = Kp // bk
 

@@ -507,6 +507,117 @@ def test_conv_hbm_bytes_slab_bigimg():
 
 
 # ---------------------------------------------------------------------------
+# wide output-channel blocks: one assembled patch tile, every 128-lane chunk
+# ---------------------------------------------------------------------------
+
+
+def _kernel_outputs(imgs, params, conv, geom, tp, bns):
+    """The raw implicit kernel output at each output block in ``bns``, on the
+    operands and image plan ``_conv_fwd_impl`` builds for ``tp``."""
+    from repro.kernels.pasm_matmul import pasm_conv_kernel_call, phase_slabs
+
+    t = params.gemm_tensor(conv.layout)
+    K, N = t.idx.shape[0] * (2 if t.packed else 1), t.idx.shape[1]
+    idxp, cbp, _ = ops._pad_weight_operands(t.idx, t.codebook, ops.LANE,
+                                            tp.gs_pad, t.packed)
+    xs = phase_slabs(ops._pad_image(imgs, geom), geom, tp.plan)
+    bias = jnp.pad(params.bias, (0, idxp.shape[1] - N)).reshape(1, -1)
+    return [np.asarray(pasm_conv_kernel_call(
+        xs, idxp, cbp, bias, geom=geom, plan=tp.plan, packed=t.packed,
+        gs=K // t.codebook.shape[0], gs_pad=tp.gs_pad, bn=bn, bk=tp.bk,
+        relu=True, interpret=True)) for bn in bns]
+
+
+@pytest.mark.parametrize("c_out,pool,slabbed,packed", [
+    (256, 1, False, False), (256, 2, True, True),
+    (384, 2, False, True), (384, 1, True, False),
+    (512, 1, False, True), (512, 2, True, False),
+])
+def test_wide_out_block_bitexact(c_out, pool, slabbed, packed):
+    """The picker widens the output-channel block to the whole padded
+    ``Np``, whole-image and slabbed (a 1-byte budget cuts the fewest rows
+    a slab of one pixel block can hold at every block width, so no width
+    adds pixel blocks; the wide rows make that one row or two): the kernel
+    output equals the ``bn = 128`` kernel's on the same operands and plan,
+    and ``conv2d`` equals the explicit engine, bit for bit."""
+    conv = cv.Conv2D(k=3, c_in=4, c_out=c_out, padding="same", relu=True)
+    ih, iw = (8, 126) if slabbed else (12, 12)
+    imgs, kern, bias = _mk(conv, hw=(ih, iw))
+    params = cv.ConvParams.quantize(kern, 16, bias=bias)
+    if packed:
+        params = params.pack()
+    budget = 1 if slabbed else None
+    geom = cv.conv_geom(conv, ih, iw, pool)
+    tp = ops.conv_tile_plan(geom, ih + 2, iw + 2, k=conv.K, n=c_out,
+                            groups=1, bins=16, packed=packed,
+                            vmem_budget=budget)
+    assert tp.bn_conv == c_out and tp.chunks_per_tile == c_out // 128
+    assert (tp.plan.n_slabs > 1) == slabbed
+    narrow, wide = _kernel_outputs(imgs, params, conv, geom, tp,
+                                   (128, tp.bn_conv))
+    np.testing.assert_array_equal(wide, narrow)
+    got = cv.conv2d(imgs, params, conv, engine="kernel_implicit",
+                    interpret=True, vmem_budget=budget, pool=pool,
+                    pool_impl="fused")
+    want = cv.conv2d(imgs, params, conv, engine="kernel", interpret=True,
+                     pool=pool, pool_impl="fused")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _bench_stage_plans(name: str, batch: int = 8):
+    """Per conv stage of a benchmark configuration (``bench/configs``), the
+    picked :class:`ops.ConvTilePlan` and the pixel blocks at ``bn = 128``."""
+    import json
+    from pathlib import Path
+
+    conf = json.loads((Path(__file__).resolve().parents[1] / "bench" /
+                       "configs" / f"{name}.json").read_text())
+    c, H, W = conf["in_chw"]
+    out = []
+    for st, pool in zip(conf["convs"], conf["pools"]):
+        conv = cv.Conv2D(k=st["k"], c_in=c, c_out=st["c_out"],
+                         stride=st["stride"], padding=conf["padding"],
+                         layout=conf["layout"], relu=True)
+        geom = cv.conv_geom(conv, H, W, pool)
+        (pt, pb), (pl, pr) = geom.pad
+        K = conv.K + (conv.K % 2 if conf["packed"] else 0)  # the §3 K-pad
+        tp = ops.conv_tile_plan(geom, H + pt + pb, W + pl + pr, k=K,
+                                n=conv.c_out, groups=1, bins=conf["bins"],
+                                packed=conf["packed"], batch=batch)
+        base = ops.conv_slab_plan(geom, H + pt + pb, W + pl + pr, bn=128,
+                                  bk=tp.bk, bins=conf["bins"],
+                                  packed=conf["packed"])
+        out.append((tp, base.n_slabs * base.n_blocks))
+        c = conv.c_out
+        H, W = cv.conv_out_hw(H, W, conv)
+        H, W = H // pool, W // pool
+    return out
+
+
+@pytest.mark.parametrize("name,table,before", [
+    ("alexnet", [(128, 1, 56), (256, 2, 304), (384, 3, 72), (384, 3, 216),
+                 (256, 2, 216)], 1960),
+    ("vgg16", [(128, 1, 792), (128, 1, 4080), (128, 1, 1040), (128, 1, 1944),
+               (256, 2, 504), (256, 2, 576), (128, 1, 1152), (512, 4, 144),
+               (512, 4, 144), (512, 4, 144), (512, 4, 72), (512, 4, 72),
+               (512, 4, 72)], 13760),
+])
+def test_conv_tile_plan_benchmark_stages(name, table, before):
+    """Pinned per stage at batch 8: ``(bn_conv, chunks_per_tile,
+    tiles_per_call)``.  No stage cuts more pixel blocks than at ``bn = 128``
+    (VGG-16 conv7 stays at 128: 256 would take 3 slabs × 3 blocks, not
+    2 × 4), and the calls' assemblies fall from ``before``, the count at
+    ``bn = 128`` everywhere."""
+    plans = _bench_stage_plans(name)
+    assert [(tp.bn_conv, tp.chunks_per_tile, tp.tiles_per_call)
+            for tp, _ in plans] == table
+    for tp, base_blocks in plans:
+        assert tp.plan.n_slabs * tp.plan.n_blocks <= base_blocks
+    assert sum(tp.tiles_per_call * tp.chunks_per_tile
+               for tp, _ in plans) == before
+
+
+# ---------------------------------------------------------------------------
 # custom VJP (explicit col2im backward)
 # ---------------------------------------------------------------------------
 

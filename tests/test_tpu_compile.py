@@ -150,3 +150,34 @@ def test_slabbed_implicit_conv_compiles(one_chip):
         _on(params, one_chip), x,
     )
     assert hlo.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("c_in,c_out,hw,pool,bn_conv,bk,n_slabs", [
+    (512, 512, 28, 2, 512, 512, 2),  # VGG-16 conv10 (conv4_3 + pool)
+    (256, 384, 14, 1, 384, 256, 1),  # AlexNet conv3
+], ids=["vgg16_conv10", "alexnet_conv3"])
+def test_wide_out_block_implicit_conv_compiles(one_chip, c_in, c_out, hw,
+                                               pool, bn_conv, bk, n_slabs):
+    """One assembled patch tile feeds every 128-lane chunk of a wide
+    output-channel block: the chunked dequant, dot and epilogue must compile
+    at the benchmark stages' real shapes, pooled and slabbed included."""
+    from repro.core import conv as cv
+
+    conv = cv.Conv2D(k=3, c_in=c_in, c_out=c_out, padding="same", relu=True)
+    geom = cv.conv_geom(conv, hw, hw, pool)
+    tp = ops.conv_tile_plan(geom, hw + 2, hw + 2, k=conv.K, n=c_out,
+                            groups=1, bins=16, packed=True)
+    assert (tp.bn_conv, tp.bk, tp.plan.n_slabs) == (bn_conv, bk, n_slabs)
+    params = jax.eval_shape(
+        lambda k: cv.ConvParams.quantize(
+            jax.random.normal(k, (c_out, c_in, 3, 3)), 16,
+            bias=jnp.zeros((c_out,)), iters=1).pack(),
+        jax.random.PRNGKey(0),
+    )
+    x = _sds((8, c_in, hw, hw), jnp.float32, one_chip)
+    hlo = _compile(
+        lambda p, x: cv.conv2d(x, p, conv, engine="kernel_implicit",
+                               interpret=False, pool=pool, pool_impl="fused"),
+        _on(params, one_chip), x,
+    )
+    assert hlo.count("tpu_custom_call") == 1
