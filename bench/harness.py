@@ -10,18 +10,37 @@ change adds files and edits none:
 - ``traffic/<mix>.json``: data only, read by ``traffic.py``.  It names its
   service loop (``loop``) and, for an open loop, its arrival process
   (``arrivals``).
-- ``loops/<loop>.py``: ``run`` drives the batcher through one window,
+- ``loops/<loop>.py``: ``run`` drives the front through one window,
   ``window_length`` says how long that window was, and ``end_to_end`` turns
   its requests into the end-to-end metrics it measures.
 - ``arrivals/<process>.py``: ``due(mix, rng, seconds)``, the times at which
   requests fall due.
 - ``metrics/<metric>.py``, else ``metrics/<metric up to its first dot>.py``:
   a reader ``read(ctx) -> float | None`` of one per-layer metric.
+- ``programs/<family>.py``, by the configuration's ``family``: the program
+  side of one family of configurations.  ``config(conf)`` and ``params(conf,
+  cfg, raw)`` put the configuration and the reference's seeded weights in the
+  program's own types; ``front(cfg, max_batch, interpret, span, clock)`` is
+  the system under test that the mix's loop drives; ``pool(mix, conf, seed)``
+  makes the seeded inputs and ``warm(front, pool)`` runs every shape they
+  reach; ``control(conf, ref)`` is the reference one precision step down,
+  called as the program's calls are; ``verify(conf, ref, raw, pool, seen)``
+  gives each number compared with its limit; ``describe(conf, batch, peak)``
+  gives the lines a traced run logs about the work.
 
-From the program the benchmark takes the system under test only: the
-``CnnBatcher`` front, and the weight container it packs the seeded weights
-into.  It reads the classify closure's logits by wrapping the closure, so the
-check compares what the timed path itself returned.
+A front keeps the seed's weights in ``params`` and records what the window
+did: ``dispatches``, the program's calls, each with its host time ``t`` and
+the request ids ``uids`` it served; ``answers`` by request id; ``flushes``
+as ``(start, seconds)``.  ``wrap``, when set, is ``wrap(key, fn) -> fn`` and
+stands in for each of the program's calls ``fn(params, inputs)``: the control
+and the planted faults use it.  The shared loops (``loops/open.py``,
+``loops/closed.py``) drive a front by three more names: ``waiting``, true
+while inputs wait; ``send(rec, pool, t, by_uid)``, which submits a request's
+pool inputs at ``t`` and files their request ids in ``rec.uids`` and
+``by_uid``; and ``serve(by_uid, t0)``, one pass over what waits, which marks
+each request ``done`` once all its inputs are answered.  A family whose
+front lacks them brings its own loop.  From the program the benchmark takes
+the system under test only; the check compares what the timed path returned.
 """
 from __future__ import annotations
 
@@ -44,7 +63,6 @@ from bench import traffic, work
 BENCH = Path(__file__).resolve().parent
 REPO = BENCH.parent
 GRACE_S = 60.0  # how long a run waits, past the window, for requests due in it
-REF_BLOCK = 16  # images per reference call
 
 
 def log(msg: str) -> None:
@@ -75,8 +93,11 @@ def load_traffic(name: str, root: Path = BENCH) -> dict:
 
 
 def _module(path: Path, name: str) -> types.ModuleType:
+    """The file at ``path`` as module ``name``, listed in ``sys.modules`` as
+    an import would list it (a dataclass in it looks itself up there)."""
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
 
@@ -94,6 +115,11 @@ def load_arrivals(name: str, root: Path = BENCH) -> Callable:
 def load_reference(conf: dict, root: Path = BENCH) -> types.ModuleType:
     path = Path(root) / "configs" / conf["reference"]
     return _module(path, f"bench_reference_{path.stem}")
+
+
+def load_program(family: str, root: Path = BENCH) -> types.ModuleType:
+    """``programs/<family>.py``: the program side of one family."""
+    return _module(Path(root) / "programs" / f"{family}.py", f"bench_program_{family}")
 
 
 def load_reader(metric: str, root: Path = BENCH) -> Callable:
@@ -114,7 +140,7 @@ def find_program(repo: Path = REPO) -> Path:
     """The program under test, ``<checkout>/src``; it is not part of the
     benchmark, so a tree holding only the benchmark cannot run."""
     src = Path(repo) / "src"
-    if not (src / "repro" / "serve" / "batcher.py").is_file():
+    if not (src / "repro").is_dir():
         raise FileNotFoundError(f"program not found under {src}")
     if str(src) not in sys.path:
         sys.path.insert(0, str(src))
@@ -130,100 +156,6 @@ def seed_key(seed: int):
         np.array([s >> 32, s & 0xFFFFFFFF], np.uint32), impl="threefry2x32")
 
 
-# -- the program's side ------------------------------------------------------
-
-
-def program_config(conf: dict):
-    """The configuration as the program's ``CNNConfig``."""
-    from repro.configs.alexnet_conv import CNNConfig
-    from repro.core.conv import Conv2D
-
-    layers, c = [], conf["in_chw"][0]
-    for s in conf["convs"]:
-        layers.append(Conv2D(k=s["k"], c_in=c, c_out=s["c_out"],
-                             stride=s["stride"], relu=True))
-        c = s["c_out"]
-    mesh = conf["mesh_shape"]
-    return CNNConfig(
-        name=conf["name"], in_chw=tuple(conf["in_chw"]), layers=tuple(layers),
-        pools=tuple(conf["pools"]), classes=conf["classes"],
-        bins=conf["bins"], impl=conf["impl"], padding=conf["padding"],
-        layout=conf["layout"], packed=conf["packed"],
-        mesh_shape=tuple(mesh) if mesh else None)
-
-
-def program_params(conf: dict, cfg, raw: dict) -> dict:
-    """The seeded weights in the program's container, packed as it serves."""
-    from repro.core.conv import ConvParams
-
-    convs = []
-    for layer in raw["conv"]:
-        p = ConvParams.shared(layer["idx"], layer["codebook"], bias=layer["bias"])
-        convs.append(p.pack(layout=cfg.layout) if conf["packed"] else p)
-    return {"conv": convs, "head": dict(raw["head"])}
-
-
-@dataclasses.dataclass
-class Dispatch:
-    """One call of a classify closure, as the harness saw it."""
-    bucket: tuple
-    t: float  # host clock at dispatch
-    logits: object  # what the closure returned, on the host once its flush ends
-    uids: list = dataclasses.field(default_factory=list)  # rows it served
-
-
-def make_batcher(cfg, max_batch: int, interpret, span, clock):
-    """A ``CnnBatcher`` whose classify calls are recorded on dispatch.
-
-    Its weights are set per seed (``params``).  ``wrap``, when set, is
-    ``wrap(bucket, fn) -> fn`` and stands in for the program's closure: the
-    control and the planted faults use it.  ``dispatches``, ``flushes`` and
-    ``answers`` collect what the service loops saw.
-    """
-    from repro.serve.batcher import CnnBatcher
-
-    class Recording(CnnBatcher):
-        def __init__(self):
-            super().__init__(cfg, None, max_batch=max_batch, interpret=interpret)
-            self.wrap = None
-            self.dispatches, self.flushes, self.answers = [], [], {}
-
-        def _classify_fn(self, bucket):
-            fn = super()._classify_fn(bucket)
-            if self.wrap is not None:
-                fn = self.wrap(bucket, fn)
-
-            def dispatch(params, images):
-                t = clock()
-                with span("bench.dispatch"):
-                    out = fn(params, images)
-                out.copy_to_host_async()
-                self.dispatches.append(Dispatch(bucket, t, out))
-                return out
-            return dispatch
-
-    return Recording()
-
-
-def attribute(served: list, dispatches: list, max_batch: int) -> None:
-    """Give each dispatch the requests it served.
-
-    ``flush`` serves each bucket's requests in chunks of ``max_batch``, one
-    closure call per chunk, and returns them in call order.
-    """
-    i = 0
-    for d in dispatches:
-        n = 0
-        while (i + n < len(served) and n < max_batch
-               and served[i + n].bucket == d.bucket):
-            n += 1
-        d.uids = [r.uid for r in served[i:i + n]]
-        i += n
-    if i != len(served):
-        raise RuntimeError(f"{len(served) - i} served requests match no "
-                           "classify call")
-
-
 # -- what the service loops share ---------------------------------------------
 
 
@@ -236,34 +168,6 @@ class Rec:
     sent: Optional[float] = None
     done: Optional[float] = None
     uids: list = dataclasses.field(default_factory=list)
-
-
-def serve(b, recs_by_uid: dict, t0: float, clock, span) -> None:
-    """One ``flush``: every waiting image classified; a request is done when
-    all of its images are.  The logits the flush's calls returned move to the
-    host (their copy began at dispatch), so the device memory the window
-    holds is the program's alone."""
-    k, start = len(b.dispatches), clock() - t0
-    with span("bench.flush"):
-        served = b.flush()
-    t = clock() - t0
-    b.flushes.append((start, t - start))
-    attribute(served, b.dispatches[k:], b.max_batch)
-    for d in b.dispatches[k:]:  # the device keeps none of the window's logits
-        d.logits = np.asarray(d.logits)
-    for r in served:
-        b.answers[r.uid] = r.cls
-        rec = recs_by_uid[r.uid]
-        if all(u in b.answers for u in rec.uids):
-            rec.done = t
-
-
-def submit(b, rec: Rec, pool: list, t: float, recs_by_uid: dict) -> None:
-    rec.sent = t
-    for j in rec.ids:
-        uid = b.submit(pool[j]).uid
-        rec.uids.append(uid)
-        recs_by_uid[uid] = rec
 
 
 def images_inside(recs: list, window_s: float) -> int:
@@ -284,55 +188,9 @@ def percentile(xs, q: float) -> float:
 # -- the check ---------------------------------------------------------------
 
 
-def reference_logits(ref, conf: dict, raw: dict, pool: list, block: int = REF_BLOCK):
-    """The reference's logits of every pool image, zero-extended to the
-    native size (what the batcher's buckets pad to), ``block`` at a time."""
-    import jax
-    import jax.numpy as jnp
-
-    C, H, W = conf["in_chw"]
-    f = jax.jit(lambda w, x: ref.forward(conf, w, x))
-    out = []
-    for i in range(0, len(pool), block):
-        x = np.zeros((block, C, H, W), np.float32)
-        for j, im in enumerate(pool[i:i + block]):
-            x[j, :, : im.shape[1], : im.shape[2]] = im
-        out.append(np.asarray(f(raw, jnp.asarray(x))))
-    return np.concatenate(out)[: len(pool)]
-
-
 def row_err(got, want) -> float:
     """``max |got - want| / max |want|`` of one image's logits."""
     return float(np.abs(got - want).max() / np.abs(want).max())
-
-
-def check(recs: list, dispatches: list, answers: dict, ref_logits, limits: dict) -> dict:
-    """Each number compared, with its limit.
-
-    - ``logit_err``: over every request due in the window, the largest
-      ``max |served logit - reference logit| / max |reference logit|`` of an
-      image, on the logits the classify closure returned.
-    - ``class_mismatch``: answers that are not the argmax of those logits.
-    - ``unanswered``: requests due in the window that never got an answer.
-    """
-    row = {}
-    for d in dispatches:
-        if d.uids:
-            logits = np.asarray(d.logits)
-            for j, u in enumerate(d.uids):
-                row[u] = logits[j]
-    err, mismatch = 0.0, 0
-    for r in recs:
-        if r.done is None:
-            continue
-        for u, pid in zip(r.uids, r.ids):
-            got, want = row[u], ref_logits[pid]
-            e = row_err(got, want)
-            err = e if not e <= err else err  # a nan sticks
-            mismatch += int(answers[u] != int(np.argmax(got)))
-    values = {"logit_err": err, "class_mismatch": mismatch,
-              "unanswered": sum(r.done is None for r in recs)}
-    return {k: {"value": v, "limit": limits[k]} for k, v in values.items()}
 
 
 def passed(numbers: dict) -> bool:
@@ -386,41 +244,31 @@ class Session:
         self.mix = load_traffic(self.spec["traffic"], root)
         self.loop = load_loop(self.mix["loop"], root)
         self.ref = load_reference(self.conf, root)
+        self.program = load_program(self.conf["family"], root)
         self.clock = time.perf_counter
         self.span = (jax.profiler.TraceAnnotation if trace
                      else lambda name: nullcontext())
-        self.cfg = program_config(self.conf)
+        self.cfg = self.program.config(self.conf)
         self._raw = jax.jit(lambda k: self.ref.weights(self.conf, k))
-        self._params = jax.jit(lambda k: program_params(
+        self._params = jax.jit(lambda k: self.program.params(
             self.conf, self.cfg, self.ref.weights(self.conf, k)))
-        self.batcher = make_batcher(self.cfg, self.mix["max_batch"], interpret,
-                                    self.span, self.clock)
+        self.batcher = self.program.front(self.cfg, self.mix["max_batch"],
+                                          interpret, self.span, self.clock)
         self.seed = None
-        self._control = self._control_fn() if control else None
-
-    def _control_fn(self):
-        """The reference at three bfloat16 passes, padding as the buckets do."""
-        import jax
-        import jax.numpy as jnp
-
-        conf, ref = self.conf, self.ref
-        _, H, W = conf["in_chw"]
-        return jax.jit(lambda w, x: ref.forward(conf, w, jnp.pad(
-            x, ((0, 0), (0, 0), (0, H - x.shape[2]), (0, W - x.shape[3]))),
-            precision="bf16x3"))
+        self._control = self.program.control(self.conf, self.ref) if control else None
 
     def load(self, seed: int) -> None:
         """The seed's weights and images; with ``control``, the control
-        takes the program's place in every classify call."""
+        takes the program's place in every call."""
         import jax
 
         self.seed = seed
         key = seed_key(seed)
         self.batcher.params = jax.block_until_ready(self._params(key))
-        self.pool = traffic.make_pool(self.mix, self.conf["in_chw"][0], seed)
+        self.pool = self.program.pool(self.mix, self.conf, seed)
         if self._control is not None:
             raw, f = self._raw(key), self._control
-            self.batcher.wrap = lambda bucket, fn: (lambda params, x: f(raw, x))
+            self.batcher.wrap = lambda _, fn: (lambda params, x: f(raw, x))
 
     def free(self) -> None:
         """Drop the program's weights, so the reference runs without them."""
@@ -428,15 +276,8 @@ class Session:
         gc.collect()
 
     def warm(self) -> None:
-        """Every bucket the pool reaches, through the timed path."""
-        b = self.batcher
-        for chunk in (self.pool, self.pool[: b.max_batch]):
-            for im in chunk:
-                b.submit(im)
-            b.flush()
-        b.dispatches.clear()
-        b.answers.clear()
-        b.flushes.clear()
+        """Every shape the pool reaches, through the timed path."""
+        self.program.warm(self.batcher, self.pool)
 
     def measure(self, seconds: float) -> types.SimpleNamespace:
         """One window of the cell's traffic; returns what it saw."""
@@ -474,11 +315,9 @@ class Session:
             flushes=list(b.flushes), gc_pauses=pauses)
 
     def verify(self, seen) -> dict:
-        """Reference logits for the pool, then every answer compared."""
+        """The reference over the pool, then every answer compared."""
         raw = self._raw(seed_key(self.seed))
-        ref = reference_logits(self.ref, self.conf, raw, self.pool)
-        return check(seen.recs, seen.dispatches, seen.answers, ref,
-                     self.conf["limits"])
+        return self.program.verify(self.conf, self.ref, raw, self.pool, seen)
 
 
 def read_per_layer(bench: dict, name: str, ctx, root: Path = BENCH) -> dict:
@@ -491,9 +330,14 @@ def read_per_layer(bench: dict, name: str, ctx, root: Path = BENCH) -> dict:
 
 
 def reader_context(sess: Session, seen, summary, device_kind: str):
+    """What a per-layer reader sees: the cell's ``conf`` and ``mix``, the
+    chip's ``peak``, the trace's ``summary`` as ``trace``, the window's whole
+    record as ``seen`` (its requests, the front's calls and answers), and
+    counts taken from it: ``calls`` and ``rows`` inside the window, and the
+    ``images`` answered there."""
     return types.SimpleNamespace(
         conf=sess.conf, mix=sess.mix, peak=work.peak(device_kind),
-        trace=summary, calls=seen.calls, rows=seen.rows,
+        trace=summary, seen=seen, calls=seen.calls, rows=seen.rows,
         max_batch=sess.mix["max_batch"], window_s=seen.window_s,
         images=images_inside(seen.recs, seen.window_s))
 

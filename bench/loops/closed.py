@@ -16,8 +16,8 @@ def run(b, pool, mix, seed, seconds, *, clock, span, root) -> tuple:
         rec = harness.Rec(now, next(requests))
         recs.append(rec)
         with span("bench.submit"):
-            harness.submit(b, rec, pool, now, by_uid)
-        harness.serve(b, by_uid, t0, clock, span)
+            b.send(rec, pool, now, by_uid)
+        b.serve(by_uid, t0)
     return recs, t0
 
 

@@ -27,10 +27,10 @@ def run(b, pool, mix, seed, seconds, *, clock, span, root) -> tuple:
             break
         with span("bench.submit"):
             while i < n and recs[i].due <= now:
-                harness.submit(b, recs[i], pool, now, by_uid)
+                b.send(recs[i], pool, now, by_uid)
                 i += 1
         if b.waiting:
-            harness.serve(b, by_uid, t0, clock, span)
+            b.serve(by_uid, t0)
         elif i < n:
             with span("bench.wait"):
                 time.sleep(max(0.0, recs[i].due - (clock() - t0)))

@@ -4,11 +4,12 @@
     python bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Set-up builds the cell's weights on the device from the seed (the plain
-reference's draw, packed into the program's container), makes the seeded
-image pool, and warms every bucket the pool reaches through the timed path.
-The window then drives ``CnnBatcher.flush`` with the cell's traffic for
-``--seconds``.  After it, the program's state is freed and every answer due
-in the window is compared with the plain reference.
+reference's draw, in the program's own container), makes the seeded inputs,
+and warms every shape they reach through the timed path; the configuration's
+family (``bench/programs/<family>.py``) says how.  The window then drives the
+program's front with the cell's traffic for ``--seconds``.  After it, the
+program's state is freed and every answer due in the window is compared with
+the plain reference.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` profiles
 the window and reports its per-layer metrics, with the device's busy time
@@ -40,7 +41,7 @@ REPO = Path(__file__).resolve().parents[1]
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 
-from bench import harness, trace as tracing, work  # noqa: E402
+from bench import harness, trace as tracing  # noqa: E402
 from bench.harness import log  # noqa: E402
 
 
@@ -120,7 +121,7 @@ def execute(sess: harness.Session, seed: int, seconds: float, *,
               "count": len(jax.devices()),
               "memory_peak_bytes": stats.get("peak_bytes_in_use")}
     result = {"correct": harness.passed(numbers), "attempted": len(seen.recs),
-              "failed": numbers["unanswered"]["value"]}
+              "failed": sum(r.done is None for r in seen.recs)}
     if trace:
         ctx = harness.reader_context(sess, seen, summary, dev.device_kind)
         result["metrics"] = harness.read_per_layer(bench, name, ctx, sess.root)
@@ -131,10 +132,8 @@ def execute(sess: harness.Session, seed: int, seconds: float, *,
         log(f"trace: {len(summary.calls)} classify runs inside the window, "
             f"{seen.calls} dispatched; busy {summary.busy_s:.6f}s of "
             f"{summary.window_s:.6f}s, Mosaic kernels {summary.kernel_busy_s:.6f}s")
-        bounds = work.conv_bounds(sess.conf, sess.mix["max_batch"], ctx.peak)
-        for st, (ai, bound) in zip(sess.conf["convs"], bounds):
-            log(f"conv stage c_out={st['c_out']} k={st['k']}: {ai:.1f} "
-                f"FLOP/byte, {bound}-bound")
+        for line in sess.program.describe(sess.conf, sess.mix["max_batch"], ctx.peak):
+            log(line)
     else:
         e2e = dict(sess.loop.end_to_end(seen.recs, seen.window_s), setup_s=setup_s)
         result["metrics"] = {

@@ -1,20 +1,24 @@
-"""The harness finds configurations, mixes and readers by name, and refuses
-to run anywhere but on the chip."""
+"""The harness finds configurations, mixes, readers and program sides by
+name, and refuses to run anywhere but on the chip."""
 import json
 import os
 import re
 import shutil
 import subprocess
 import sys
+import time
 from pathlib import Path
 
 import pytest
 
-from bench import harness
+from bench import harness, run
 
 REPO = Path(__file__).resolve().parents[2]
 TESTS = Path(__file__).resolve().parent
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+TOY = TESTS / "toy"  # a family of its own, laid out as under bench/
+PROGRAM_SIDE = ("config", "params", "front", "pool", "warm", "control",
+                "verify", "describe")
 
 
 @pytest.fixture
@@ -88,6 +92,9 @@ def test_benchmark_json_names_only_what_exists():
         assert conf["name"] == c["name"] and conf["reduced"] == c["reduced"]
         assert {"source", "reduced", "assumed", "limits"} <= set(conf)
         assert (REPO / "bench" / "configs" / conf["reference"]).is_file()
+        assert (REPO / "bench" / "programs" / f"{conf['family']}.py").is_file()
+        program = harness.load_program(conf["family"])
+        assert all(callable(getattr(program, f)) for f in PROGRAM_SIDE)
     for w in bench["workloads"]:
         harness.load_config(w["config"])
         mix = harness.load_traffic(w["traffic"])
@@ -127,3 +134,85 @@ def test_benchmark_alone_does_not_run(tmp_path):
     assert p.returncode == 2
     assert "program not found" in p.stderr
     assert not p.stdout.strip()
+
+
+def _bench_files() -> list:
+    """The files a copy of the benchmark takes from ``bench/``."""
+    top = REPO / "bench"
+    return sorted(p.relative_to(top) for p in top.rglob("*") if p.is_file()
+                  and not {"tests", "__pycache__"} & set(p.relative_to(top).parts))
+
+
+@pytest.fixture
+def toy_root(tmp_path):
+    """A copy of the benchmark with the toy family's files added: its
+    program side, configuration, reference, mix and loop."""
+    r = tmp_path / "bench"
+    shutil.copytree(REPO / "bench", r, ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    for f in sorted(TOY.rglob("*")):
+        if f.is_file() and "__pycache__" not in f.parts:
+            dst = r / f.relative_to(TOY)
+            assert not dst.exists(), f"{dst} would be edited, not added"
+            shutil.copy(f, dst)
+    return r
+
+
+def _toy_session(root, traffic="toy", **kw) -> harness.Session:
+    bench = harness.load_benchmark()
+    name = f"toy.{traffic}"
+    bench["workloads"].append({"name": name, "config": "toy",
+                               "traffic": traffic, "chips": 1})
+    return harness.Session(bench, name, interpret=True, root=root, **kw)
+
+
+def _plant_wrong_answer(key, fn):
+    """One answer altered where the front produces it."""
+    return lambda params, x: fn(params, x).at[0, 0].add(1.0)
+
+
+def test_new_family_added_as_files_runs_end_to_end(toy_root):
+    conf = harness.load_config("toy", toy_root)
+    assert conf["family"] == "toy" and not {"convs", "in_chw"} & set(conf)
+    sess = _toy_session(toy_root)
+    sess.load(2**31 + 29)
+    sess.warm()
+    seen = sess.measure(0.3)
+    assert seen.recs and seen.calls > 0 and seen.compiles == 0
+    sess.free()
+    numbers = sess.verify(seen)
+    assert harness.passed(numbers), numbers
+
+    r = run.execute(sess, 2**33 + 1, 0.3, t_start=time.perf_counter())
+    assert r["correct"] and r["failed"] == 0 and r["attempted"] > 0
+    assert set(r["metrics"]) == {"images_per_s", "setup_s"}
+    assert set(r["check"]) == {"score_err", "unanswered"}
+
+    sess.batcher.wrap = _plant_wrong_answer
+    r = run.execute(sess, 2**33 + 2, 0.3, t_start=time.perf_counter())
+    assert not r["correct"]
+    assert r["check"]["score_err"]["value"] > r["check"]["score_err"]["limit"]
+
+    for rel in _bench_files():  # nothing the benchmark had was edited
+        assert (toy_root / rel).read_bytes() == (REPO / "bench" / rel).read_bytes(), rel
+
+
+def test_new_family_control_is_not_correct(toy_root):
+    sess = _toy_session(toy_root, control=True)
+    r = run.execute(sess, 2**33 + 3, 0.3, t_start=time.perf_counter())
+    assert not r["correct"]
+    assert r["check"]["score_err"]["value"] > r["check"]["score_err"]["limit"]
+
+
+def test_new_family_runs_under_a_shared_loop(toy_root):
+    """A front with ``waiting``, ``send`` and ``serve`` needs no loop of its
+    own: the toy family's second mix names the closed loop that the CNN
+    cells use."""
+    sess = _toy_session(toy_root, traffic="toy_closed")
+    assert sess.mix["loop"] == "closed"
+    r = run.execute(sess, 2**33 + 4, 0.3, t_start=time.perf_counter())
+    assert r["correct"] and r["failed"] == 0 and r["attempted"] > 0
+    assert r["metrics"]["images_per_s"]["value"] > 0
+
+    sess.batcher.wrap = _plant_wrong_answer
+    r = run.execute(sess, 2**33 + 5, 0.3, t_start=time.perf_counter())
+    assert not r["correct"]

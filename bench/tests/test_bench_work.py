@@ -1,4 +1,5 @@
 """The work counts and peaks the kernels are read against."""
+import dataclasses
 import json
 
 import numpy as np
@@ -68,8 +69,34 @@ def test_geometry_matches_the_program(name):
     from repro.models import cnn
 
     c = conf(name)
-    cfg = harness.program_config(c)
+    cfg = harness.load_program(c["family"]).config(c)
     for st, (conv, pool) in zip(ref.stages(c), cnn.stages(cfg)):
         assert conv_out_hw(st["ih"], st["iw"], conv) == (st["oh"], st["ow"])
         assert pool == st["pool"]
     assert ref.feature_size(c) == int(np.prod(cnn.feature_shape(cfg)))
+
+
+@pytest.mark.parametrize("name", ["alexnet", "vgg16"])
+def test_cnn_family_config_is_the_program_config_it_was(name):
+    """Every ``CNNConfig`` and ``Conv2D`` field is the configuration's, or
+    the default where the configuration gives none."""
+    c = conf(name)
+    got = harness.load_program("cnn").config(c)
+    chans = [c["in_chw"][0]] + [s["c_out"] for s in c["convs"]]
+    assert [(l.k, l.c_in, l.c_out, l.stride, l.relu) for l in got.layers] == [
+        ((s["k"], s["k"]), ci, s["c_out"], s["stride"], True)
+        for s, ci in zip(c["convs"], chans)]
+    for layer in got.layers:  # the rest of each stage keeps the default
+        for f in dataclasses.fields(layer):
+            if f.name not in {"k", "c_in", "c_out", "stride", "relu"}:
+                assert getattr(layer, f.name) == f.default, f.name
+    assert (got.name, got.in_chw, got.pools, got.mesh_shape) == (
+        c["name"], tuple(c["in_chw"]), tuple(c["pools"]),
+        tuple(c["mesh_shape"]) if c["mesh_shape"] else None)
+    for k in ("classes", "bins", "impl", "padding", "layout", "packed"):
+        assert getattr(got, k) == c[k], k
+    rest = {"name", "in_chw", "layers", "pools", "mesh_shape", "classes", "bins",
+            "impl", "padding", "layout", "packed"}
+    for f in dataclasses.fields(got):  # what it never set keeps the default
+        if f.name not in rest:
+            assert getattr(got, f.name) == f.default, f.name
